@@ -24,7 +24,7 @@ from repro.models.registry import (
     prefill as _prefill,
     train_loss,
 )
-from repro.optim import adamw_update, compress_state_init, ef_compress
+from repro.optim import adamw_init, adamw_update, ef_compress
 
 from .sharding import cache_logical_axes, make_rules, pspec_for_axes, shardings_for
 
@@ -70,6 +70,18 @@ def make_train_state_specs(model):
         "step": (),
     }
     return state_shapes, state_axes
+
+
+def init_train_state(model, key) -> dict:
+    """Fresh {params, opt, step} from ``key``. Jit it with the state
+    shardings as ``out_shardings`` so the state is made where it lives,
+    never whole on one device."""
+    params, _ = model.init(key)
+    return {
+        "params": params,
+        "opt": adamw_init(params),
+        "step": jnp.zeros((), jnp.int32),
+    }
 
 
 def make_batch_specs(cfg, kind: str, global_batch: int, seq_len: int) -> dict:
@@ -185,16 +197,14 @@ def make_train_step(
             if compress:
                 # gradients crossing the slow pod links go int8 + error
                 # feedback; in-pod reductions stay f32 (XLA native)
-                from jax.experimental.shard_map import shard_map
-
                 gspecs = jax.tree.map(lambda s: s.spec, state_shard["params"])
                 cspecs = {"residual": gspecs}
-                grads, cstate, _ = shard_map(
+                grads, cstate, _ = jax.shard_map(
                     functools.partial(ef_compress, axis_name="pod", n_pods=n_pods),
                     mesh=mesh,
                     in_specs=(gspecs, cspecs),
                     out_specs=(gspecs, cspecs, P()),
-                    check_rep=False,
+                    check_vma=False,
                 )(grads, state["compress"])
                 new_state["compress"] = cstate
 

@@ -14,18 +14,22 @@ Sliding-window / causal predication happens at two levels:
   1. whole-block skip via ``pl.when`` (no MXU work issued for dead tiles),
   2. elementwise masking on the boundary tiles.
 
-Validated on CPU via ``interpret=True`` against ``ref.reference_attention``.
+Runs compiled on a TPU and interpreted elsewhere; tests compare both with
+``ref.reference_attention``.
 """
 
 from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from . import resolve_interpret
 
 NEG_INF = -2.0e38
 
@@ -122,7 +126,7 @@ def flash_attention(
     window: int = 0,
     block_q: int = 128,
     block_kv: int = 128,
-    interpret: bool = True,  # CPU container: interpret; on TPU pass False
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     B, Lq, H, Dh = q.shape
     Lk, KVH = k.shape[1], k.shape[2]
@@ -177,7 +181,7 @@ def flash_attention(
             pltpu.VMEM((gq * block_q, 128), jnp.float32),
             pltpu.VMEM((gq * block_q, Dh), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qf, kf, vf)
 
     # unfold: (B*KVH, nq*gq*block_q, D) -> (B, Lq, H, D)

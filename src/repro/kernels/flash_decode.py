@@ -9,31 +9,37 @@ resident across all kv steps.
 Masking is position-based (matches ``models.attention._cached_attention``):
 a per-slot position array handles both linear caches (pos = slot index) and
 SWA ring buffers (pos = stored absolute position); slots beyond the write
-index are invalid.
+index are invalid and carry a position no query reaches.
 
-Validated on CPU via ``interpret=True`` against ``ref.reference_decode``.
+The per-slot positions and the query position travel as 3-D arrays whose
+last two block dims equal the array's, (1, block_kv) and (1, 1), which the
+chip's (8, 128) tiling rule accepts. Runs compiled on a TPU and interpreted
+elsewhere; tests compare both with ``ref.reference_decode``.
 """
 
 from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import resolve_interpret
+
 NEG_INF = -2.0e38
+_NEVER = jnp.iinfo(jnp.int32).max  # position of an unwritten slot
 
 
 def _decode_kernel(
     q_ref,  # (1, gq, d)
     k_ref,  # (1, bkv, d)
     v_ref,  # (1, bkv, d)
-    pos_ref,  # (1, bkv) s32 per-slot absolute positions
-    qpos_ref,  # (1, 1) s32 current query position
-    valid_ref,  # (1, bkv) s32 1 = slot written
+    pos_ref,  # (1, 1, bkv) s32 per-slot absolute positions (_NEVER = unwritten)
+    qpos_ref,  # (1, 1, 1) s32 current query position
     o_ref,  # (1, gq, d)
     m_scr,  # (gq, 128)
     l_scr,  # (gq, 128)
@@ -60,12 +66,12 @@ def _decode_kernel(
         )
         * scale
     )  # (gq, bkv)
-    kpos = pos_ref[0]  # (bkv,)
-    qpos = qpos_ref[0, 0]
-    ok = (kpos <= qpos) & (valid_ref[0] > 0)
+    kpos = pos_ref[0]  # (1, bkv)
+    qpos = qpos_ref[0]  # (1, 1)
+    ok = kpos <= qpos
     if window > 0:
         ok &= kpos > qpos - window
-    s = jnp.where(ok[None, :], s, NEG_INF)
+    s = jnp.where(ok, s, NEG_INF)
 
     m_prev = m_scr[:, 0]
     l_prev = l_scr[:, 0]
@@ -96,7 +102,7 @@ def flash_decode(
     *,
     window: int = 0,
     block_kv: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     B, Lq, H, Dh = q.shape
     assert Lq == 1, "flash_decode is single-token"
@@ -110,18 +116,17 @@ def flash_decode(
     if pad:
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        k_pos = jnp.pad(k_pos, ((0, 0), (0, pad)))
     Sp = S + pad
 
     # fold: (B, 1, KVH, gq, d) -> (B*KVH, gq, d); KV -> (B*KVH, Sp, d)
     qf = q.reshape(B, KVH, gq, Dh).reshape(B * KVH, gq, Dh)
     kf = k.transpose(0, 2, 1, 3).reshape(B * KVH, Sp, Dh)
     vf = v.transpose(0, 2, 1, 3).reshape(B * KVH, Sp, Dh)
-    slot = jnp.arange(Sp)[None, :]
-    valid = (slot < (n_valid[:, None] + 0)) & (slot < S)
-    posf = jnp.repeat(k_pos, KVH, axis=0)  # (B*KVH, Sp)
-    validf = jnp.repeat(valid.astype(jnp.int32), KVH, axis=0)
-    qposf = jnp.repeat(q_pos[:, None].astype(jnp.int32), KVH, axis=0)  # (B*KVH,1)
+    slot = jnp.arange(S)[None, :]
+    pos = jnp.where(slot < n_valid[:, None], k_pos.astype(jnp.int32), _NEVER)
+    pos = jnp.pad(pos, ((0, 0), (0, pad)), constant_values=_NEVER)
+    posf = jnp.repeat(pos, KVH, axis=0)[:, None, :]  # (B*KVH, 1, Sp)
+    qposf = jnp.repeat(q_pos.astype(jnp.int32), KVH)[:, None, None]  # (B*KVH, 1, 1)
 
     kernel = functools.partial(_decode_kernel, window=window, scale=scale)
     out = pl.pallas_call(
@@ -131,9 +136,8 @@ def flash_decode(
             pl.BlockSpec((1, gq, Dh), lambda b, ki: (b, 0, 0)),
             pl.BlockSpec((1, block_kv, Dh), lambda b, ki: (b, ki, 0)),
             pl.BlockSpec((1, block_kv, Dh), lambda b, ki: (b, ki, 0)),
-            pl.BlockSpec((1, block_kv), lambda b, ki: (b, ki)),
-            pl.BlockSpec((1, 1), lambda b, ki: (b, 0)),
-            pl.BlockSpec((1, block_kv), lambda b, ki: (b, ki)),
+            pl.BlockSpec((1, 1, block_kv), lambda b, ki: (b, 0, ki)),
+            pl.BlockSpec((1, 1, 1), lambda b, ki: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, gq, Dh), lambda b, ki: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B * KVH, gq, Dh), q.dtype),
@@ -142,6 +146,6 @@ def flash_decode(
             pltpu.VMEM((gq, 128), jnp.float32),
             pltpu.VMEM((gq, Dh), jnp.float32),
         ],
-        interpret=interpret,
-    )(qf, kf, vf, posf, qposf, validf)
+        interpret=resolve_interpret(interpret),
+    )(qf, kf, vf, posf, qposf)
     return out.reshape(B, KVH, gq, Dh).reshape(B, 1, H, Dh)

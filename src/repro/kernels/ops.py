@@ -1,9 +1,9 @@
 """Jit'd kernel wrappers + the kernel registry handed to the models.
 
-``kernel_set(use_pallas, interpret)`` returns the dict that
-``repro.models`` threads through the layers: on TPU the Pallas kernels run
-compiled; on CPU they run in interpret mode (tests) or the models fall back
-to the pure-jnp references (fast path for CI).
+``kernel_set(use_pallas)`` returns the dict that ``repro.models`` threads
+through the layers. Each kernel derives its mode from the platform
+(``resolve_interpret``): compiled on a TPU, interpreted elsewhere. Without
+``use_pallas`` the models take their pure-jnp paths.
 """
 
 from __future__ import annotations
@@ -13,7 +13,6 @@ from typing import Optional
 
 import jax
 
-from . import ref
 from .flash_attention import flash_attention
 from .flash_decode import flash_decode
 from .hash_tree import hash_tree_state
@@ -21,59 +20,21 @@ from .mamba_scan import mamba_scan
 from .moe_gmm import moe_gmm
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window", "block_q", "block_kv", "interpret"))
-def flash_attention_op(q, k, v, *, causal=True, window=0, block_q=128, block_kv=128, interpret=True):
+@functools.partial(jax.jit, static_argnames=("causal", "window", "block_q", "block_kv"))
+def flash_attention_op(q, k, v, *, causal=True, window=0, block_q=128, block_kv=128):
     return flash_attention(
-        q, k, v, causal=causal, window=window,
-        block_q=block_q, block_kv=block_kv, interpret=interpret,
+        q, k, v, causal=causal, window=window, block_q=block_q, block_kv=block_kv
     )
 
 
-@functools.partial(jax.jit, static_argnames=("chunk_len", "d_block", "interpret"))
-def mamba_scan_op(xc, dt, Bm, Cm, a, h0=None, *, chunk_len=256, d_block=512, interpret=True):
-    return mamba_scan(
-        xc, dt, Bm, Cm, a, h0,
-        chunk_len=chunk_len, d_block=d_block, interpret=interpret,
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("block_c", "block_f", "interpret"))
-def moe_gmm_op(x, w_gate, w_up, w_down, *, block_c=128, block_f=256, interpret=True):
-    return moe_gmm(
-        x, w_gate, w_up, w_down,
-        block_c=block_c, block_f=block_f, interpret=interpret,
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("blocks_per_chunk", "interpret"))
-def hash_tree_op(words, *, blocks_per_chunk=64, interpret=True):
-    return hash_tree_state(
-        words, blocks_per_chunk=blocks_per_chunk, interpret=interpret
-    )
-
-
-def kernel_set(use_pallas: bool, interpret: bool = True) -> Optional[dict]:
-    """The dict the model trunk consumes (keys: moe_gmm, mamba_scan)."""
+def kernel_set(use_pallas: bool) -> Optional[dict]:
+    """The dict the model trunk consumes (keys: moe_gmm, mamba_scan,
+    flash_decode, hash_tree)."""
     if not use_pallas:
         return None
-
-    def _gmm(x, wg, wu, wd):
-        return moe_gmm(x, wg, wu, wd, interpret=interpret)
-
-    def _scan(xc, dt, Bm, Cm, a, h0=None, chunk_len=256):
-        return mamba_scan(xc, dt, Bm, Cm, a, h0, chunk_len=chunk_len, interpret=interpret)
-
-    def _decode(q, k, v, k_pos, q_pos, n_valid, window=0):
-        return flash_decode(
-            q, k, v, k_pos, q_pos, n_valid, window=window, interpret=interpret
-        )
-
-    def _hash_tree(words):
-        return hash_tree_state(words, interpret=interpret)
-
     return {
-        "moe_gmm": _gmm,
-        "mamba_scan": _scan,
-        "flash_decode": _decode,
-        "hash_tree": _hash_tree,
+        "moe_gmm": moe_gmm,
+        "mamba_scan": mamba_scan,
+        "flash_decode": flash_decode,
+        "hash_tree": hash_tree_state,
     }

@@ -34,6 +34,7 @@ from repro.dist.step import (
     make_train_step,
 )
 from repro.dist.sharding import make_rules
+from repro.launch.device import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.models.registry import build_model
 from repro.optim import cosine_warmup
@@ -222,6 +223,7 @@ def _save(rec: dict, multi_pod: bool, arch: str, shape: str, tag: str = ""):
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

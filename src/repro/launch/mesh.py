@@ -11,16 +11,27 @@ must keep seeing 1 device).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes, devices=None):
+    """A mesh whose axes are all ``Auto``: the steps place data with
+    ``with_sharding_constraint`` and leave propagation to the compiler, which
+    ``jax.make_mesh``'s default ``Explicit`` axes refuse."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(AxisType.Auto,) * len(axes), devices=devices
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
-def make_host_mesh(model: int = 1):
-    """Tiny mesh over whatever devices exist (tests / examples on CPU)."""
-    n = len(jax.devices())
+def make_host_mesh(model: int = 1, devices=None):
+    """Mesh over ``devices`` (default: every device this process sees)."""
+    devices = list(jax.devices() if devices is None else devices)
+    n = len(devices)
     assert n % model == 0
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"), devices=devices)

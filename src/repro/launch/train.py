@@ -10,11 +10,18 @@ driver restores the latest checkpoint AV and replays.
 CPU quickstart (reduced config):
   PYTHONPATH=src python -m repro.launch.train --arch stablelm-1.6b \
       --reduced --steps 20 --batch 8 --seq 128
+
+The train state is made sharded on the mesh, never whole on one device.
+Each step's time ends in ``block_until_ready``; ``--ckpt-every 0`` turns
+checkpoints off.
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
+import os
+import tempfile
 import time
 
 import jax
@@ -25,13 +32,16 @@ from repro.configs import get_config
 from repro.core import ProvenanceRegistry, software_version_of
 from repro.data.pipeline import build_data_pipeline, next_batch
 from repro.dist.ft import FaultToleranceManager, SimulatedFailure
+from repro.dist.step import init_train_state
+from repro.launch.device import device_info, enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.registry import build_model, train_loss
-from repro.optim import adamw_init, cosine_warmup
+from repro.optim import cosine_warmup
 from repro.workspace import MeshExecutor
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="stablelm-1.6b")
     ap.add_argument("--reduced", action="store_true")
@@ -41,13 +51,17 @@ def main(argv=None):
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--microbatches", type=int, default=1)
     ap.add_argument("--ckpt-every", type=int, default=10)
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    ap.add_argument(
+        "--ckpt-dir", default=os.path.join(tempfile.gettempdir(), "repro_ckpt")
+    )
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--fail-at-step", type=int, default=-1,
                     help="inject a simulated host failure (tests recovery)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    dev = device_info()
+    print(f"device {dev['platform']} {dev['kind']} x{dev['count']}")
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -71,12 +85,9 @@ def main(argv=None):
     ft = FaultToleranceManager(n_hosts=jax.process_count())
 
     def fresh_state():
-        params, _ = model.init(jax.random.key(args.seed))
-        return {
-            "params": params,
-            "opt": adamw_init(params),
-            "step": jax.numpy.zeros((), jax.numpy.int32),
-        }
+        return jax.jit(
+            functools.partial(init_train_state, model), out_shardings=state_shard
+        )(jax.random.key(args.seed))
 
     def restore():
         last = ckpt.latest_step()
@@ -89,7 +100,7 @@ def main(argv=None):
     def run(start_state, start_step):
         state = start_state
         for step in range(start_step, args.steps):
-            t0 = time.time()
+            t0 = time.perf_counter()
             batch = next_batch(data, cfg)
             batch = {k: jax.numpy.asarray(v) for k, v in batch.items()}
             if cfg.encoder_layers and "frames" not in batch:
@@ -107,7 +118,8 @@ def main(argv=None):
                     dtype=jax.numpy.float32,
                 )
             state, metrics = jitted(state, batch)
-            dt = time.time() - t0
+            jax.block_until_ready(metrics)
+            dt = time.perf_counter() - t0
             ft.heartbeat(0, dt)
             registry.log_visit("train_step", f"step-{step}", "executed", sw,
                                note=f"loss={float(metrics['loss']):.4f} wall={dt:.3f}s")
@@ -119,7 +131,9 @@ def main(argv=None):
                 f"lr {float(metrics['lr']):.2e} gnorm {float(metrics['grad_norm']):.3f} "
                 f"({dt:.2f}s)"
             )
-            if (step + 1) % args.ckpt_every == 0 or step + 1 == args.steps:
+            if args.ckpt_every and (
+                (step + 1) % args.ckpt_every == 0 or step + 1 == args.steps
+            ):
                 ckpt.save_async(state, step + 1, meta={"loss": float(metrics["loss"])})
         ckpt.wait()
         return state

@@ -179,11 +179,14 @@ def _zero_pad_rows(pair, n_real: int):
 def init_attention(pb: ParamBuilder, cfg: ArchConfig) -> dict:
     d, H, KVH, Dh = cfg.d_model, cfg.n_heads_eff, cfg.n_kv_heads, cfg.head_dim
     assert H % KVH == 0, f"padded heads {H} must stay a multiple of kv={KVH}"
+    # 3-D weights name their fan-in: the contracted dims, not shape[-2]
     p = {
-        "wq": pb.dense((d, H, Dh), ("embed", "heads", "head_dim")),
-        "wk": pb.dense((d, KVH, Dh), ("embed", "kv_heads", "head_dim")),
-        "wv": pb.dense((d, KVH, Dh), ("embed", "kv_heads", "head_dim")),
-        "wo": pb.dense((H, Dh, d), ("heads", "head_dim", "embed")),
+        "wq": pb.dense((d, H, Dh), ("embed", "heads", "head_dim"), scale=d**-0.5),
+        "wk": pb.dense((d, KVH, Dh), ("embed", "kv_heads", "head_dim"), scale=d**-0.5),
+        "wv": pb.dense((d, KVH, Dh), ("embed", "kv_heads", "head_dim"), scale=d**-0.5),
+        "wo": pb.dense(
+            (H, Dh, d), ("heads", "head_dim", "embed"), scale=(cfg.n_heads * Dh) ** -0.5
+        ),
     }
     if cfg.pad_heads:
         p["wo"] = _zero_pad_rows(p["wo"], cfg.n_heads)
@@ -335,12 +338,14 @@ def init_mla(pb: ParamBuilder, cfg: ArchConfig) -> dict:
     p = {
         "wq_a": pb.dense((d, qr), ("embed", "q_lora")),
         "q_norm": pb.ones((qr,), ("q_lora",)),
-        "wq_b": pb.dense((qr, H, dn + dr), ("q_lora", "heads", "head_dim")),
+        "wq_b": pb.dense((qr, H, dn + dr), ("q_lora", "heads", "head_dim"), scale=qr**-0.5),
         "wkv_a": pb.dense((d, kvr + dr), ("embed", "kv_lora")),
         "kv_norm": pb.ones((kvr,), ("kv_lora",)),
-        "wk_b": pb.dense((kvr, H, dn), ("kv_lora", "heads", "head_dim")),
-        "wv_b": pb.dense((kvr, H, dv), ("kv_lora", "heads", "head_dim")),
-        "wo": pb.dense((H, dv, d), ("heads", "head_dim", "embed")),
+        "wk_b": pb.dense((kvr, H, dn), ("kv_lora", "heads", "head_dim"), scale=kvr**-0.5),
+        "wv_b": pb.dense((kvr, H, dv), ("kv_lora", "heads", "head_dim"), scale=kvr**-0.5),
+        "wo": pb.dense(
+            (H, dv, d), ("heads", "head_dim", "embed"), scale=(cfg.n_heads * dv) ** -0.5
+        ),
     }
     if cfg.pad_heads:
         p["wo"] = _zero_pad_rows(p["wo"], cfg.n_heads)

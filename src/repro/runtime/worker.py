@@ -23,7 +23,9 @@ Request kinds:
   stop        acknowledge and exit cleanly
   ==========  ===========================================================
 
-Fork discipline: the parent flushes its journal before every spawn (a
+Fork discipline: a parent that holds an accelerator through JAX never
+forks (:func:`refuse_fork_holding_device`). The parent flushes its journal
+before every spawn (a
 buffered line must not be double-written by two processes), and the child's
 first act is to *neutralize* every inherited journal binding — close the fd,
 mark the journal closed, unhook registry/cache/ledger write-through — so the
@@ -48,6 +50,29 @@ try:
     _CTX = get_context("fork")
 except (ImportError, ValueError):  # pragma: no cover - non-POSIX platforms
     _CTX = None
+
+
+def refuse_fork_holding_device() -> None:
+    """Raise before forking a worker from a process that already holds an
+    accelerator through JAX. The child would inherit the parent's device
+    client but not the device, which belongs to one process at a time: on a
+    TPU such a child hangs on its first JAX call, even one that only reads
+    an existing array. A CPU backend, or JAX not yet initialised, forks as
+    before."""
+    import sys
+
+    if "jax" not in sys.modules:
+        return
+    import jax
+    from jax._src import xla_bridge
+
+    if xla_bridge.backends_are_initialized() and jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"cannot fork a Koalja worker: this process already holds the "
+            f"{jax.default_backend()} device through JAX, and a forked child "
+            f"cannot use it. Run tasks that touch the device with the inline "
+            f"or concurrent executor."
+        )
 
 
 def fork_context():
@@ -92,6 +117,7 @@ class WorkerProcess:
             raise RuntimeError(
                 "repro.runtime requires the 'fork' start method (POSIX only)"
             )
+        refuse_fork_holding_device()
         if manager.journal is not None:
             # buffered journal lines must reach disk before the fork — the
             # child closes its inherited fd without flushing, and a line

@@ -17,10 +17,7 @@ import tempfile
 import numpy as np
 import pytest
 
-try:  # real hypothesis if installed; seeded-random fallback otherwise
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # pragma: no cover - depends on environment
-    from repro.testing.hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.provenance import ProvenanceRegistry
 from repro.provenance import (

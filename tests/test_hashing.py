@@ -9,10 +9,7 @@ import pickle
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # hermetic containers: seeded-random fallback
-    from repro.testing.hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.av import content_hash as content_hash_av
 from repro.core.hashing import (

@@ -1,5 +1,6 @@
-"""Integration: the model trunk with Pallas kernels (interpret mode) must
-match the pure-jnp reference path — the exact swap that happens on TPU."""
+"""Integration: the model trunk with Pallas kernels (interpreted off the
+TPU) must match the pure-jnp reference path — the exact swap that happens
+on TPU."""
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +28,7 @@ def test_trunk_with_pallas_kernels_matches_reference(arch):
 
     loss_ref, _ = train_loss(model, params, batch, kernels=None)
     loss_krn, _ = train_loss(
-        model, params, batch, kernels=kernel_set(use_pallas=True, interpret=True)
+        model, params, batch, kernels=kernel_set(use_pallas=True)
     )
     assert float(loss_ref) == pytest.approx(float(loss_krn), rel=2e-4), arch
 

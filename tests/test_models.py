@@ -161,3 +161,24 @@ def test_greedy_generate_deterministic():
     g2 = greedy_generate(m, params, prompt, n_steps=8, max_len=32)
     np.testing.assert_array_equal(np.asarray(g1), np.asarray(g2))
     assert g1.shape == (2, 8)
+
+
+@pytest.mark.parametrize("arch", ["stablelm-1.6b", "minicpm3-4b"])
+def test_deep_model_is_not_chaotic_at_init(arch):
+    """Attention weights are drawn at 1/sqrt(contracted dims). With the
+    fan-in of a 3-D weight read as shape[-2], q/k/v came out several times
+    too large and a 24-layer model amplified a 1e-6 input change into O(1)
+    logits, so no decode-vs-teacher check of a deep model could pass."""
+    cfg = dataclasses.replace(get_config(arch).reduced(), n_layers=24)
+    m = build_model(cfg)
+    params, _ = m.init(jax.random.key(0))
+    toks = jax.random.randint(jax.random.key(1), (1, 16), 0, cfg.vocab)
+    pos = jnp.arange(16)[None]
+
+    @jax.jit
+    def logits(eps):
+        x, _, _ = m.trunk(params, m.embed(params, toks) * (1 + eps), pos)
+        return m.logits(params, x)
+
+    a, b = logits(0.0), logits(1e-6)
+    assert float(jnp.abs(a - b).max() / jnp.abs(a).max()) < 1e-4

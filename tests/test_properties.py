@@ -2,10 +2,7 @@
 
 import numpy as np
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # hermetic containers: seeded-random fallback
-    from repro.testing.hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import ContentCache, InputSpec, SnapshotPolicy, snapshot_key
 from repro.optim import dequantize_int8, quantize_int8

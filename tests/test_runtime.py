@@ -252,6 +252,27 @@ class TestProcessPool:
         finally:
             ex.shutdown()
 
+    @pytest.mark.parametrize("platform", ["tpu", "cpu"])
+    def test_refuses_to_fork_while_holding_an_accelerator(self, platform, monkeypatch):
+        """A forked child cannot use a device its parent holds: once JAX has
+        an accelerator backend, a multi-task wave raises instead of forking.
+        The CPU backend forks as before."""
+        import jax
+
+        jax.numpy.zeros(1).block_until_ready()  # backends initialised
+        monkeypatch.setattr(jax, "default_backend", lambda: platform)
+        ex = ProcessExecutor(max_workers=2)
+        try:
+            if platform == "tpu":
+                with pytest.raises(RuntimeError, match="cannot fork"):
+                    _drive_fan(_fan_ws(ex, width=2), rounds=1)
+                assert ex.stats()["workers_alive"] == 0
+            else:
+                _drive_fan(_fan_ws(ex, width=2), rounds=1)
+                assert ex.stats()["tasks_remote"] > 0
+        finally:
+            ex.shutdown()
+
 
 @needs_fork
 class TestWorkerCrash:

@@ -15,10 +15,7 @@ import threading
 
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # hermetic containers: seeded-random fallback
-    from repro.testing.hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.tenancy import (
     PermissionDeniedError,

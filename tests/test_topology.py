@@ -6,10 +6,7 @@ property on reducer fan-ins."""
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # hermetic containers: seeded-random fallback
-    from repro.testing.hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.topology import (
     DataGravityPlacement,
