@@ -61,7 +61,10 @@ import json
 import os
 import pickle
 import struct
+import sys
 from typing import Any, Callable, Iterable, List, Optional, Sequence
+
+from .spans import enabled, span
 
 __all__ = [
     "content_hash",
@@ -96,6 +99,7 @@ _STATS = {
     "pickle_hashes": 0,  # payloads hashed via the pickle tier
     "unstable_hashes": 0,  # repr fallbacks (pickle failed) — process-local!
     "backend_fallbacks": 0,  # jnp/pallas kernel failures rescued by numpy
+    "d2h_bytes": 0,  # bytes copied from a device to the host to be hashed
 }
 
 _HASH_BACKENDS = ("numpy", "jnp", "pallas")
@@ -333,9 +337,19 @@ class _SmallArray:
         self.index = index
 
 
-def _classify(payload: Any, out: list, small: list, on_unstable) -> None:
+def _device_nbytes(payload) -> int:
+    """Bytes ``np.asarray`` copies from a device to the host for
+    ``payload``: a ``jax.Array``'s size, 0 for an array already on the host."""
+    jax = sys.modules.get("jax")
+    if jax is not None and isinstance(payload, jax.Array):
+        return int(payload.nbytes)
+    return 0
+
+
+def _classify(payload: Any, out: list, small: list, on_unstable, moved: list) -> None:
     """Hash one payload, or defer it into ``small`` for the fused pass.
-    Appends the digest (or a placeholder) to ``out``."""
+    Appends the digest (or a placeholder) to ``out``; adds an array's bytes,
+    and those copied from a device to hash it, to ``moved``."""
     try:  # numpy-like arrays
         import numpy as np
 
@@ -348,7 +362,10 @@ def _classify(payload: Any, out: list, small: list, on_unstable) -> None:
                     )
                 )
                 return
+            d2h = _device_nbytes(payload)
             arr = np.asarray(payload)
+            moved[0] += payload.nbytes
+            moved[1] += d2h
             if arr.dtype.hasobject:
                 # Object arrays serialize as pointers under tobytes();
                 # that digest was always address-garbage — pickle instead.
@@ -430,10 +447,15 @@ def content_hash_batch(
     _STATS["payloads"] += len(payloads)
     out: list = []
     small: List[_SmallArray] = []
-    for payload in payloads:
-        _classify(payload, out, small, on_unstable)
-    if small:
-        _fuse_small(small, out)
+    moved = [0, 0]  # array bytes hashed, bytes copied device -> host
+    with span("hash", payloads=len(payloads)) as sp:
+        for payload in payloads:
+            _classify(payload, out, small, on_unstable, moved)
+        if small:
+            _fuse_small(small, out)
+        _STATS["d2h_bytes"] += moved[1]
+        if enabled():
+            sp.set_metadata(nbytes=moved[0], d2h_bytes=moved[1])
     return out
 
 

@@ -35,6 +35,7 @@ from typing import Any, Iterable, Optional, Union
 import numpy as np
 
 from .hashing import content_hash_batch
+from .spans import enabled, span
 
 
 class _Timer:
@@ -243,13 +244,17 @@ class ArtifactStore:
         batch-hashed the payloads (e.g. ``finish_execution``) skip the
         rehash. Returns ``[(uri, chash, nbytes), ...]``."""
         payloads = list(payloads)
-        if hashes is None:
-            hashes = content_hash_batch(payloads, on_unstable=self._on_unstable)
-        sizes = [self._nbytes(p) for p in payloads]
-        out = []
-        with self._lock:
-            for payload, h, nbytes in zip(payloads, hashes, sizes):
-                out.append((self._put_locked(payload, h, nbytes, prefer), h, nbytes))
+        with span("store.put") as sp:
+            if hashes is None:
+                hashes = content_hash_batch(payloads, on_unstable=self._on_unstable)
+            sizes = [self._nbytes(p) for p in payloads]
+            out = []
+            with self._lock:
+                for payload, h, nbytes in zip(payloads, hashes, sizes):
+                    out.append((self._put_locked(payload, h, nbytes, prefer), h, nbytes))
+            if enabled():
+                tiers = sorted({uri.split("://", 1)[0] for uri, _, _ in out})
+                sp.set_metadata(nbytes=sum(sizes), tier=",".join(tiers))
         return out
 
     def _put_locked(self, payload: Any, h: str, nbytes: int, prefer: Optional[str]) -> str:
@@ -287,20 +292,22 @@ class ArtifactStore:
                 f"materialize (§III.K); the spec rides on the AV metadata"
             )
         self.gets += 1
-        t0 = time.perf_counter()
-        if tier == "local":
-            with self._lock:
-                if h in self._local:
-                    payload = self._local[h]
-                    self._local.move_to_end(h)
-                    self._lat["local"].add(time.perf_counter() - t0)
-                    return payload
-            if not self._in_object(h):
-                raise KeyError(h)
-        path = self._object_path(h)
-        with open(path, "rb") as f:
-            payload = self._load(f)
-        self._lat["object"].add(time.perf_counter() - t0)
+        with span("store.get", nbytes=self._sizes.get(h, 0), tier=tier) as sp:
+            t0 = time.perf_counter()
+            if tier == "local":
+                with self._lock:
+                    if h in self._local:
+                        payload = self._local[h]
+                        self._local.move_to_end(h)
+                        self._lat["local"].add(time.perf_counter() - t0)
+                        return payload
+                if not self._in_object(h):
+                    raise KeyError(h)
+                sp.set_metadata(tier="object")  # spilled since the URI was issued
+            path = self._object_path(h)
+            with open(path, "rb") as f:
+                payload = self._load(f)
+            self._lat["object"].add(time.perf_counter() - t0)
         return payload
 
     def pin_local(self, uri: str, *, region: Optional[str] = None) -> str:

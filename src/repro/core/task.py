@@ -25,6 +25,7 @@ from .av import AnnotatedValue, content_hash, is_ghost
 from .hashing import content_hash_batch
 from .policy import InputSpec, SnapshotPolicy
 from .provenance import ProvenanceRegistry
+from .spans import PUSH, span
 from .store import ArtifactStore
 
 
@@ -488,9 +489,10 @@ class SmartTask:
         for sname, svc in self.services.items():
             kwargs[sname] = svc
 
-        t0 = time.perf_counter()
-        result = self.fn(**kwargs)
-        dt = time.perf_counter() - t0
+        with span("task", task=self.name, push=PUSH.get()):
+            t0 = time.perf_counter()
+            result = self.fn(**kwargs)
+            dt = time.perf_counter() - t0
         return result, dt
 
     def finish_execution(

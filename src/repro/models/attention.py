@@ -59,7 +59,7 @@ def blocked_attention(
     """Online-softmax attention, O(block) memory. Returns (B, Lq, H, Dv).
 
     v's head dim may differ from q/k's (MLA: Dk=96, Dv=64)."""
-    with jax.named_scope("pallas_flash_attention"):
+    with jax.named_scope("jnp_attention"):
         return _blocked_attention(
             q, k, v, causal=causal, window=window,
             block_q=block_q, block_kv=block_kv, causal_skip=causal_skip,
@@ -287,7 +287,7 @@ def _cached_attention(q, k, v, k_pos, q_pos, valid, cfg: ArchConfig):
     per-slot positions. q: (B, L, H, Dh); k/v: (B, S, KVH, Dh). The cache's
     seq axis may be sharded (flash-decoding layout) — the reductions below
     then lower to per-shard partial softmax + cross-shard combine."""
-    with jax.named_scope("pallas_flash_attention"):
+    with jax.named_scope("jnp_attention"):
         return _cached_attention_impl(q, k, v, k_pos, q_pos, valid, cfg)
 
 

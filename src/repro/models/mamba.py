@@ -78,7 +78,7 @@ def selective_scan(
     chunk_len: int = 256,
 ):
     """Chunked selective scan. Returns (y: (B,L,Di) f32, h_final: (B,Di,N))."""
-    with jax.named_scope("pallas_mamba_scan"):
+    with jax.named_scope("jnp_mamba_scan"):
         return _selective_scan_impl(xc, dt, Bm, Cm, a, h0, chunk_len)
 
 

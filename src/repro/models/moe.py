@@ -121,10 +121,11 @@ def moe_ffn(
     xe = shard(xe, "moe_group", "experts", None, None)
 
     # 3. grouped SwiGLU — the moe_gmm hook
-    with jax.named_scope("pallas_moe_gmm"):
-        if gmm is not None and G == 1:
+    if gmm is not None and G == 1:
+        with jax.named_scope("pallas_moe_gmm"):
             h = gmm(xe[0], p["w_gate"], p["w_up"], p["w_down"])[None]
-        else:
+    else:
+        with jax.named_scope("jnp_moe_gmm"):
             g = jnp.einsum("gecd,edf->gecf", xe, p["w_gate"])
             u = jnp.einsum("gecd,edf->gecf", xe, p["w_up"])
             h = jnp.einsum("gecf,efd->gecd", jax.nn.silu(g) * u, p["w_down"])

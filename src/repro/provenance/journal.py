@@ -64,6 +64,8 @@ import threading
 import time
 from typing import Any, Iterable, Optional
 
+from repro.core.spans import span
+
 FORMAT_VERSION = 1
 
 # rotated segments: <path>.0001, <path>.0002, ... (live tail is <path>)
@@ -288,7 +290,7 @@ class Journal:
         self._staging = threading.local()
         self.records_written = 0
         self.flushes = 0
-        self.encode_wall_s = 0.0  # cumulative record-encode time (stats())
+        self.fsync_s = 0.0  # cumulative time in flush + fsync (stats())
         self.rotations = 0
         self.compactions = 0
         # cumulative across the journal's lifetime (reseeded from the
@@ -455,10 +457,9 @@ class Journal:
         records = list(records)
         if not records:
             return []
-        with self._lock:
+        with span("journal.append", records=len(records)), self._lock:
             if self.closed:
                 raise ValueError(f"journal {self.path} is closed")
-            t0 = time.perf_counter()
             seqs: list = []
             lines: list = []
             # Delegated seq space: claim the whole batch's numbers from the
@@ -490,7 +491,6 @@ class Journal:
                     self._next_seq = max(self._next_seq, seq + 1)
                 lines.append(encode_record(seq, kind, data))
                 seqs.append(seq)
-            self.encode_wall_s += time.perf_counter() - t0
             self._fh.write("\n".join(lines) + "\n")
             n = len(lines)
             self.records_written += n
@@ -522,15 +522,13 @@ class Journal:
                 self._next_seq += 1
         else:
             self._next_seq = max(self._next_seq, seq + 1)
-        t0 = time.perf_counter()
-        line = encode_record(seq, kind, data)
-        self.encode_wall_s += time.perf_counter() - t0
-        self._fh.write(line + "\n")
-        self.records_written += 1
-        self._live_records += 1
-        self._pending += 1
-        if self._pending >= self.flush_every_n:
-            self._flush_locked()
+        with span("journal.append", records=1):
+            self._fh.write(encode_record(seq, kind, data) + "\n")
+            self.records_written += 1
+            self._live_records += 1
+            self._pending += 1
+            if self._pending >= self.flush_every_n:
+                self._flush_locked()
         return seq
 
     def _maybe_rotate_locked(self) -> None:
@@ -745,8 +743,11 @@ class Journal:
             }
 
     def _flush_locked(self) -> None:
-        self._fh.flush()
-        os.fsync(self._fh.fileno())
+        with span("journal.fsync"):
+            t0 = time.perf_counter()
+            self._fh.flush()
+            os.fsync(self._fh.fileno())
+            self.fsync_s += time.perf_counter() - t0
         self.flushes += 1
         self._pending = 0
 
@@ -802,7 +803,7 @@ class Journal:
                 ),
                 "flushes": self.flushes,
                 "flush_every_n": self.flush_every_n,
-                "encode_wall_s": self.encode_wall_s,
+                "fsync_s": self.fsync_s,
                 "next_seq": self._next_seq,
                 "segments": len(chain["segments"])
                 + (1 if chain["live"] else 0),

@@ -17,6 +17,11 @@ Assumptions (documented, deliberately simple):
   - flash attention re-streams K/V once per Q block row (grid order);
   - per-device sizes divide by the shard counts actually achieved by the
     rules (divisibility-checked — replicated dims divide by 1).
+
+Only a region lowered under a ``pallas_*`` named scope is substituted. The
+jnp attention, selective scan and grouped matmul run under ``jnp_*`` scopes,
+since no Pallas kernel runs in their place, so a program that calls no
+kernel is credited nothing.
 """
 
 from __future__ import annotations
@@ -128,7 +133,9 @@ def apply_kernel_credit(
     credited = raw_traffic
     detail = {}
     for name, kio in io.items():
-        braw = buckets.get(name, {}).get("traffic_bytes", 0.0)
+        if name not in buckets:
+            continue  # the kernel is not in the program: nothing to substitute
+        braw = buckets[name].get("traffic_bytes", 0.0)
         credited = credited - braw + kio
         detail[name] = {"raw_bytes": braw, "kernel_io_bytes": kio}
     return {"corrected_traffic": max(credited, 0.0), "detail": detail}

@@ -23,6 +23,7 @@ merge-FCFS snapshots are identical across backends.
 
 from __future__ import annotations
 
+import contextvars
 import os
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Optional, Protocol, runtime_checkable
@@ -141,9 +142,12 @@ class ConcurrentExecutor(InlineExecutor):
         self.parallel_waves += 1
         self.tasks_parallel += len(tasks)
         pool = self._ensure_pool()
+        # each worker runs in a copy of the caller's context, so its spans
+        # carry the push they belong to
         futures = [
             pool.submit(
-                t.execute, manager.store, manager.registry, manager.cache, emit=False
+                contextvars.copy_context().run,
+                t.execute, manager.store, manager.registry, manager.cache, emit=False,
             )
             for t in tasks
         ]

@@ -34,6 +34,7 @@ from repro.core.av import AnnotatedValue
 from repro.core.pipeline import Pipeline, PipelineManager
 from repro.core.policy import InputSpec
 from repro.core.provenance import ProvenanceRegistry
+from repro.core.spans import PUSH, install_gc_hook, span
 from repro.core.store import ArtifactStore
 from repro.core.task import ServiceCall, SmartTask
 from repro.topology import Topology, default_topology
@@ -209,6 +210,8 @@ class Workspace:
         self._handles: dict = {}
         self._manager: Optional[PipelineManager] = None
         self._watchers: list = []
+        self._pushes = 0  # the ``push`` argument of this workspace's spans
+        install_gc_hook()
 
     def _make_journal(
         self, journal_path, flush_every_n, rotate_bytes=None, rotate_records=None
@@ -516,8 +519,15 @@ class Workspace:
         """Reactive mode: deliver payloads to the task's inputs and let the
         event drive computation downstream."""
         mgr = self._build()
-        fired = self.executor.push(mgr, self._name_of(task), payloads, region)
-        self._notify_watchers(fired)
+        name = self._name_of(task)
+        self._pushes += 1
+        token = PUSH.set(self._pushes)
+        try:
+            with span("push", push=self._pushes, task=name):
+                fired = self.executor.push(mgr, name, payloads, region)
+                self._notify_watchers(fired)
+        finally:
+            PUSH.reset(token)
         return RunResult(self, fired)
 
     def sample(self, source: TaskRef) -> RunResult:

@@ -539,9 +539,16 @@ class TestBatchAppend:
         assert truncated == 0
         assert [r["seq"] for r in records] == list(range(len(records)))
 
-    def test_encode_wall_s_counter(self, tmp_path):
-        j = Journal(str(tmp_path / "w.jsonl"))
-        j.append_batch([("visit", {"n": i}) for i in range(100)])
+    def test_append_batch_span(self, tmp_path):
+        import spantrace
+
+        j = Journal(str(tmp_path / "w.jsonl"), flush_every_n=64)
+        batch = [("visit", {"n": i}) for i in range(100)]
+        _, found = spantrace.record(lambda: j.append_batch(batch), tmp_path / "trace")
         st = j.stats()
         j.close()
-        assert st["encode_wall_s"] > 0.0
+        appends = [s for s in found if s.name == "koalja:journal.append"]
+        assert [s.args["records"] for s in appends] == [100]
+        fsyncs = [s for s in found if s.name == "koalja:journal.fsync"]
+        assert len(fsyncs) == 1 and fsyncs[0].within(appends[0])
+        assert st["flushes"] == 1 and st["fsync_s"] > 0.0 and "encode_wall_s" not in st
