@@ -39,6 +39,7 @@ from typing import Optional
 
 from jax.sharding import NamedSharding, PartitionSpec
 
+from repro.models.attention import kv_cache_layout
 from repro.models.common import ArchConfig
 
 
@@ -170,11 +171,13 @@ def cache_logical_axes(cfg: ArchConfig, max_len: int) -> list:
                 "k_rope": ("batch", "kv_seq", None),
                 "index": (),
             }
-        c = {
-            "k": ("batch", "kv_seq", "kv_heads", "head_dim"),
-            "v": ("batch", "kv_seq", "kv_heads", "head_dim"),
-            "index": (),
-        }
+        heads_major, _ = kv_cache_layout(cfg)
+        kv = (
+            ("batch", "kv_heads", "kv_seq", "head_dim")
+            if heads_major
+            else ("batch", "kv_seq", "kv_heads", "head_dim")
+        )
+        c = {"k": kv, "v": kv, "index": ()}
         S = (
             min(max_len, cfg.window)
             if (cfg.attention == "swa" and cfg.window)

@@ -20,7 +20,15 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from .common import ArchConfig, ParamBuilder, apply_rope, rms_norm, shard
+from .common import (
+    ArchConfig,
+    ParamBuilder,
+    apply_rope,
+    cache_layer,
+    cache_write,
+    rms_norm,
+    shard,
+)
 
 NEG_INF = -2.0e38
 
@@ -216,10 +224,15 @@ def attention_block(
     cfg: ArchConfig,
     x: jax.Array,  # (B, L, D)
     positions: jax.Array,  # (B, L) absolute positions
-    cache: Optional[dict] = None,  # see init_attention_cache
+    cache: Optional[dict] = None,  # see init_attention_cache, stacked (G, ...)
     cross_kv: Optional[tuple] = None,  # (k, v) encoder memory for cross-attn
+    layer: Optional[jax.Array] = None,  # this layer's slot in ``cache``
 ):
-    """Self-attention with optional KV cache (decode) — returns (y, new_cache)."""
+    """Self-attention with optional KV cache (decode) — returns (y, new_cache).
+
+    ``cache`` holds every layer's cache stacked over the scan groups; this
+    layer writes its new rows at ``layer`` in place and attends over its
+    slot where it lies."""
     B, L, _ = x.shape
     if cross_kv is not None:
         q = jnp.einsum("bld,dhk->blhk", x, p["wq"])
@@ -246,26 +259,40 @@ def attention_block(
         )
         new_cache = None
     else:
-        idx = cache["index"]  # scalar int32: #tokens already in cache
-        S = cache["k"].shape[1]
+        heads_major, Dp = kv_cache_layout(cfg)
+        seq = 2 if heads_major else 1  # slot axis of one layer's (B, ., ., Dp) cache
+        S = cache["k"].shape[1 + seq]
+        Dh = k.shape[-1]
+        idx = cache_layer(cache["index"], layer)  # int32: tokens already cached
+        total = idx + L
+
+        def stored(t):  # (B, L, KVH, Dh) rows as the cache holds them
+            t = jnp.pad(t, ((0, 0),) * 3 + ((0, Dp - Dh),)).astype(cache["k"].dtype)
+            return jnp.moveaxis(t, 1, seq)
+
         if "pos" in cache:  # SWA ring buffer of size W
             wpos = jnp.mod(idx + jnp.arange(L), S)  # (L,)
-            ck = cache["k"].at[:, wpos].set(k)
-            cv = cache["v"].at[:, wpos].set(v)
-            kpos = cache["pos"].at[:, wpos].set(positions)
-            total = idx + L
+            # the index arrays (layer, wpos) put the L rows first
+            at = (layer,) + (slice(None),) * seq + (wpos,)
+            ck = cache["k"].at[at].set(jnp.moveaxis(stored(k), seq, 0))
+            cv = cache["v"].at[at].set(jnp.moveaxis(stored(v), seq, 0))
+            kpos = cache["pos"].at[layer, :, wpos].set(positions.T)
             valid = jnp.arange(S)[None, :] < total  # ring: slot written yet?
-            out = _cached_attention(q, ck, cv, kpos, positions, valid, cfg)
-            new_cache = {"k": ck, "v": cv, "pos": kpos, "index": total}
+            out = _cached_attention(
+                q, cache_layer(ck, layer), cache_layer(cv, layer),
+                cache_layer(kpos, layer), positions, valid, cfg,
+            )
+            new_cache = {"k": ck, "v": cv, "pos": kpos}
         else:
-            ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, idx, axis=1)
-            cv = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, idx, axis=1)
-            total = idx + L
+            at = (0,) * seq + (idx,)
+            ck = cache_write(cache["k"], stored(k), layer, *at)
+            cv = cache_write(cache["v"], stored(v), layer, *at)
+            k_l, v_l = cache_layer(ck, layer), cache_layer(cv, layer)
             if L > 1:
                 # prefill: blocked attention over the cache (slots >= L are
                 # causally dead for a fresh cache; prefill starts at idx=0)
                 out = blocked_attention(
-                    q, ck, cv,
+                    q, jnp.moveaxis(k_l, seq, 1)[..., :Dh], jnp.moveaxis(v_l, seq, 1)[..., :Dh],
                     causal=True,
                     window=cfg.window if cfg.attention == "swa" else 0,
                     block_q=cfg.block_q, block_kv=cfg.block_kv,
@@ -274,8 +301,9 @@ def attention_block(
             else:
                 valid = jnp.arange(S)[None, :] < total
                 kpos = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
-                out = _cached_attention(q, ck, cv, kpos, positions, valid, cfg)
-            new_cache = {"k": ck, "v": cv, "index": total}
+                out = _cached_attention(q, k_l, v_l, kpos, positions, valid, cfg)
+            new_cache = {"k": ck, "v": cv}
+        new_cache["index"] = cache_write(cache["index"], total, layer)
 
     out = shard(out, "batch", "seq", "heads", None)
     y = jnp.einsum("blhk,hkd->bld", out, p["wo"])
@@ -283,36 +311,66 @@ def attention_block(
 
 
 def _cached_attention(q, k, v, k_pos, q_pos, valid, cfg: ArchConfig):
-    """Decode-path attention over a (possibly ring) cache with explicit
-    per-slot positions. q: (B, L, H, Dh); k/v: (B, S, KVH, Dh). The cache's
-    seq axis may be sharded (flash-decoding layout) — the reductions below
-    then lower to per-shard partial softmax + cross-shard combine."""
+    """Decode-path attention over one layer's (possibly ring) cache with
+    explicit per-slot positions. q: (B, L, H, Dh); k/v as the cache stores
+    them (``kv_cache_layout``), head dim padded. The cache's seq axis may be
+    sharded (flash-decoding layout) — the reductions below then lower to
+    per-shard partial softmax + cross-shard combine."""
     with jax.named_scope("jnp_attention"):
         return _cached_attention_impl(q, k, v, k_pos, q_pos, valid, cfg)
 
 
 def _cached_attention_impl(q, k, v, k_pos, q_pos, valid, cfg: ArchConfig):
     B, L, H, Dh = q.shape
-    S, KVH = k.shape[1], k.shape[2]
+    heads_major, Dp = kv_cache_layout(cfg)
+    kv = "bhkd" if heads_major else "bkhd"
+    KVH = cfg.n_kv_heads
     Gq = H // KVH
-    qg = q.reshape(B, L, KVH, Gq, Dh)
-    s = _gqa_scores(qg, k) * (Dh**-0.5)  # (B,KVH,Gq,L,S)
+    # zero lanes past Dh add nothing to the scores, and their outputs are cut
+    qg = jnp.pad(q, ((0, 0),) * 3 + ((0, Dp - Dh),)).reshape(B, L, KVH, Gq, Dp)
+    s = jnp.einsum(f"bqhgd,{kv}->bhgqk", qg, k, preferred_element_type=jnp.float32)
+    s = s * (Dh**-0.5)  # (B,KVH,Gq,L,S)
     ok = k_pos[:, None, :] <= q_pos[:, :, None]  # (B, L, S) causal
     if cfg.attention == "swa" and cfg.window > 0:
         ok &= k_pos[:, None, :] > (q_pos[:, :, None] - cfg.window)
     ok &= valid[:, None, :]
     s = s + jnp.where(ok, 0.0, NEG_INF)[:, None, None]  # (B,1,1,L,S)
     pw = jax.nn.softmax(s.astype(jnp.float32), axis=-1)
-    out = jnp.einsum("bhgqk,bkhd->bhgqd", pw, v, preferred_element_type=jnp.float32)
-    return out.transpose(0, 3, 1, 2, 4).reshape(B, L, H, Dh).astype(q.dtype)
+    out = jnp.einsum(f"bhgqk,{kv}->bhgqd", pw, v, preferred_element_type=jnp.float32)
+    out = out[..., :Dh].transpose(0, 3, 1, 2, 4)
+    return out.reshape(B, L, H, Dh).astype(q.dtype)
+
+
+LANES = 128  # a TPU vector register's lanes: the tile width of a minor dim
+
+
+def kv_cache_layout(cfg: ArchConfig) -> tuple:
+    """(heads_major, padded head dim) of the attention KV cache.
+
+    Chosen so that the TPU's default layout of the stored cache is the one
+    the decode step works in, so that XLA re-lays no cache-sized buffer at a
+    step's entry or exit:
+
+    - the head dim is padded with zeros to whole lanes: the layout decode
+      reads pads a narrower minor dim in memory anyway, and a logical shape
+      that holds the padding keeps the default layout from moving the slot
+      axis minor instead;
+    - with several query heads per KV head (GQA) the scores are a matmul per
+      KV head, which XLA lays out heads-major, ``(B, KVH, S, Dp)``; with one
+      (MHA) they are a multiply-reduce, laid out slot-major, ``(B, S, KVH, Dp)``.
+    """
+    heads_major = cfg.n_heads_eff // cfg.n_kv_heads > 1
+    return heads_major, -(-cfg.head_dim // LANES) * LANES
 
 
 def init_attention_cache(cfg: ArchConfig, batch: int, max_len: int, dtype) -> dict:
     S = min(max_len, cfg.window) if (cfg.attention == "swa" and cfg.window) else max_len
-    KVH, Dh = cfg.n_kv_heads, cfg.head_dim
+    heads_major, Dp = kv_cache_layout(cfg)
+    KVH = cfg.n_kv_heads
+    shape = (batch, KVH, S, Dp) if heads_major else (batch, S, KVH, Dp)
     cache = {
-        "k": jnp.zeros((batch, S, KVH, Dh), dtype),
-        "v": jnp.zeros((batch, S, KVH, Dh), dtype),
+        "k": jnp.zeros(shape, dtype),
+        "v": jnp.zeros(shape, dtype),
         "index": jnp.zeros((), jnp.int32),
     }
     if cfg.attention == "swa" and cfg.window and S == cfg.window:
@@ -357,7 +415,8 @@ def mla_block(
     cfg: ArchConfig,
     x: jax.Array,
     positions: jax.Array,
-    cache: Optional[dict] = None,
+    cache: Optional[dict] = None,  # see init_mla_cache, stacked (G, ...)
+    layer: Optional[jax.Array] = None,  # this layer's slot in ``cache``
 ):
     B, L, _ = x.shape
     dn, dr = cfg.qk_nope_dim, cfg.qk_rope_dim
@@ -391,11 +450,15 @@ def mla_block(
         y = jnp.einsum("blhk,hkd->bld", o, p["wo"])
         return y, None
 
-    idx = cache["index"]
-    c_kv = jax.lax.dynamic_update_slice_in_dim(cache["c_kv"], c_kv, idx, axis=1)
-    k_rope = jax.lax.dynamic_update_slice_in_dim(cache["k_rope"], k_rope, idx, axis=1)
+    idx = cache_layer(cache["index"], layer)
     total = idx + L
-    new_cache = {**cache, "c_kv": c_kv, "k_rope": k_rope, "index": total}
+    new_cache = {
+        "c_kv": cache_write(cache["c_kv"], c_kv, layer, 0, idx),
+        "k_rope": cache_write(cache["k_rope"], k_rope, layer, 0, idx),
+        "index": cache_write(cache["index"], total, layer),
+    }
+    c_kv = cache_layer(new_cache["c_kv"], layer)
+    k_rope = cache_layer(new_cache["k_rope"], layer)
     S = c_kv.shape[1]
 
     if L > 1:

@@ -310,6 +310,25 @@ def stack_groups(pairs_list):
 
 
 # ---------------------------------------------------------------------------
+# Serve caches stacked over the scan groups
+# ---------------------------------------------------------------------------
+
+
+def cache_layer(a: jax.Array, layer: jax.Array) -> jax.Array:
+    """Layer ``layer`` of a cache leaf stacked over the scan groups, read
+    where it lies (XLA fuses the slice into its reader)."""
+    return jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+
+
+def cache_write(a: jax.Array, rows: jax.Array, layer: jax.Array, *at) -> jax.Array:
+    """``a`` with one layer's new ``rows`` written at ``(layer, *at)``, the
+    dims ``at`` leaves out at 0: the stacked cache updated in place, nothing
+    else of it touched."""
+    start = (layer, *at) + (0,) * (a.ndim - 1 - len(at))
+    return jax.lax.dynamic_update_slice(a, rows[None].astype(a.dtype), start)
+
+
+# ---------------------------------------------------------------------------
 # Norms & RoPE
 # ---------------------------------------------------------------------------
 

@@ -19,7 +19,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from .common import ArchConfig, ParamBuilder, shard
+from .common import ArchConfig, ParamBuilder, cache_layer, cache_write, shard
 
 
 def init_mamba(pb: ParamBuilder, cfg: ArchConfig) -> dict:
@@ -129,8 +129,9 @@ def mamba_block(
     cfg: ArchConfig,
     x: jax.Array,  # (B, L, D)
     positions: jax.Array,  # unused (kept for mixer-uniform signature)
-    cache: Optional[dict] = None,  # {"h": (B,Di,N), "conv": (B,K-1,Di)}
+    cache: Optional[dict] = None,  # {"h": (G,B,Di,N), "conv": (G,B,K-1,Di)}
     scan_impl: Optional[object] = None,  # Pallas selective scan on TPU
+    layer: Optional[jax.Array] = None,  # this layer's slot in ``cache``
 ):
     B, L, D = x.shape
     di, n, K = cfg.d_inner, cfg.ssm_state, cfg.ssm_conv
@@ -139,6 +140,9 @@ def mamba_block(
     xr = shard(xr, "batch", "seq", "inner")
     a = -jnp.exp(p["a_log"])  # (Di, N)
 
+    stacked = cache
+    if cache is not None:  # this layer's slot of the stacked state
+        cache = {k: cache_layer(c, layer) for k, c in cache.items()}
     if cache is None:
         xc = jax.nn.silu(_causal_conv(xr, p["conv_w"], p["conv_b"]))
         dt, Bm, Cm = _ssm_params(p, cfg, xc)
@@ -171,6 +175,8 @@ def mamba_block(
         y, h_final = scan(xc, dt, Bm, Cm, a, h0=cache["h"], chunk_len=min(256, L))
         new_cache = {"h": h_final, "conv": conv_in[:, -(K - 1) :]}
 
+    if new_cache is not None:  # the layer's small state replaced in its slot
+        new_cache = {k: cache_write(stacked[k], c, layer) for k, c in new_cache.items()}
     y = y + xcf_skip(xc, p["d_skip"])
     y = (y * jax.nn.silu(z.astype(jnp.float32))).astype(x.dtype)
     out = jnp.einsum("bli,id->bld", y, p["out_proj"])

@@ -78,9 +78,10 @@ def apply_layer(
     spec: LayerSpec,
     x: jax.Array,
     positions: jax.Array,
-    cache: Optional[dict],
+    cache: Optional[dict],  # every layer's cache, stacked (G, ...)
     memory: Optional[jax.Array],  # encoder output for cross-attention
     kernels: Optional[dict] = None,
+    layer: Optional[jax.Array] = None,  # this layer's slot in ``cache``
 ):
     """Returns (x, new_cache, aux_loss)."""
     aux = jnp.zeros((), jnp.float32)
@@ -88,12 +89,15 @@ def apply_layer(
     h = _rms(x, p["ln1"], cfg.norm_eps)
     if spec.mixer == "attention":
         if cfg.attention == "mla":
-            y, new_cache = attn.mla_block(p["mixer"], cfg, h, positions, cache)
+            y, new_cache = attn.mla_block(p["mixer"], cfg, h, positions, cache, layer=layer)
         else:
-            y, new_cache = attn.attention_block(p["mixer"], cfg, h, positions, cache)
+            y, new_cache = attn.attention_block(
+                p["mixer"], cfg, h, positions, cache, layer=layer
+            )
     else:
         y, new_cache = mb.mamba_block(
-            p["mixer"], cfg, h, positions, cache, scan_impl=kernels.get("mamba_scan")
+            p["mixer"], cfg, h, positions, cache,
+            scan_impl=kernels.get("mamba_scan"), layer=layer,
         )
     x = x + y
 
@@ -201,30 +205,41 @@ class Model:
         kernels: Optional[dict] = None,
     ):
         cfg = self.cfg
+        aux0 = jnp.zeros((), jnp.float32)
 
-        def body(carry, xs):
-            x, aux = carry
-            p_gs, c_gs = xs
-            new_cs = []
-            for spec, p_g, c_g in zip(cfg.layout, p_gs, c_gs):
-                x, nc, a = apply_layer(
-                    p_g, cfg, spec, x, positions, c_g, memory, kernels
-                )
-                aux = aux + a
-                new_cs.append(nc)
-            return (x, aux), new_cs
-
-        # remat only matters under autodiff; serve paths (caches present)
-        # skip it — no backward, and checkpoint would rewrite op metadata.
         if caches is None:
-            body = _maybe_remat(body, cfg)
-        caches_in = caches if caches is not None else [None] * len(cfg.layout)
-        (x, aux), new_caches = jax.lax.scan(
-            body,
-            (x, jnp.zeros((), jnp.float32)),
-            (list(params["blocks"]), caches_in),
+
+            def body(carry, p_gs):
+                x, aux = carry
+                for spec, p_g in zip(cfg.layout, p_gs):
+                    x, _, a = apply_layer(p_g, cfg, spec, x, positions, None, memory, kernels)
+                    aux = aux + a
+                return (x, aux), None
+
+            # remat only matters under autodiff: the serve path skips it
+            (x, aux), _ = jax.lax.scan(
+                _maybe_remat(body, cfg), (x, aux0), list(params["blocks"])
+            )
+            return x, aux, None
+
+        # serve: the stacked caches ride in the carry and each layer writes
+        # its new rows into them in place, at its index
+        def serve_body(carry, xs):
+            x, aux, cs = carry
+            p_gs, g = xs
+            new_cs = []
+            for spec, p_g, c in zip(cfg.layout, p_gs, cs):
+                x, c, a = apply_layer(p_g, cfg, spec, x, positions, c, memory, kernels, g)
+                aux = aux + a
+                new_cs.append(c)
+            return (x, aux, new_cs), None
+
+        (x, aux, caches), _ = jax.lax.scan(
+            serve_body,
+            (x, aux0, list(caches)),
+            (list(params["blocks"]), jnp.arange(cfg.n_groups, dtype=jnp.int32)),
         )
-        return x, aux, (new_caches if caches is not None else None)
+        return x, aux, caches
 
     # -- heads --------------------------------------------------------------
     def embed(self, params: dict, tokens: jax.Array) -> jax.Array:

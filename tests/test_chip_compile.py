@@ -1,14 +1,18 @@
-"""Compile the main path's kernels and the stablelm-1.6b decode step for one
-TPU v5e chip, at full width, without a chip: the TPU compiler refuses what
-the chip would refuse (tiling, scoped VMEM, unsupported primitives), which
-interpret mode never shows.
+"""Compile the main path's kernels and the serve decode step for one TPU
+v5e chip, at full width, without a chip: the TPU compiler refuses what the
+chip would refuse (tiling, scoped VMEM, unsupported primitives), which
+interpret mode never shows. The decode step is also held to updating its
+KV cache in place: no copy, re-lay or slice of a cache-sized buffer.
 
 The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU library, and every
 test worker imports every test file.
 """
 
+import dataclasses
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -120,3 +124,105 @@ def test_stablelm_decode_step_compiles_for_v5e(topo, no_persistent_cache):
     mem = compiled.memory_analysis()
     # bf16 params (3.3 GB) plus a 4 x 2048 KV cache (1.6 GB) fit one 16 GB chip
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+_DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4}
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$")
+_INSTRUCTION = re.compile(
+    r"^\s*(ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(([^)]*)\)(.*)$"
+)
+
+
+def cache_sized_moves(hlo: str, nbytes: int) -> list:
+    """Instructions of a compiled module that move ``nbytes`` or more of a
+    buffer without computing on it: a copy, copy-start or transpose anywhere
+    (a re-lay, fused or not), a dynamic-update-slice writing that much, and
+    a dynamic-slice, or a fusion that ends in one of these, whose result is
+    put in memory. A slice fused into the op that reads it is a read where
+    the buffer lies, and passes."""
+    comps: dict = {}
+    instrs: dict = {}
+    for line in hlo.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            instrs = comps.setdefault(m.group(1), {})
+            continue
+        m = _INSTRUCTION.match(line)
+        if m:
+            root, name, dt, dims, op, operands, attrs = m.groups()
+            size = math.prod(int(d) for d in dims.split(",") if d) * _DTYPE_BYTES.get(dt, 4)
+            callee = re.search(r"calls=%([\w.\-]+)", attrs)
+            instrs[name] = (op, size, re.findall(r"%([\w.\-]+)", operands),
+                            callee and callee.group(1), bool(root))
+    fused = set(re.findall(r" fusion\(.*calls=%([\w.\-]+)", hlo))
+
+    def root_op(comp):
+        body = comps.get(comp, {})
+        for op, _, args, _, is_root in body.values():
+            if is_root:
+                while op == "bitcast" and args[0] in body:
+                    op, _, args, _, _ = body[args[0]]
+                return op
+
+    found = []
+    for comp, body in comps.items():
+        for name, (op, size, args, callee, _) in body.items():
+            if op in ("copy", "copy-start", "transpose") and size >= nbytes:
+                found.append(f"{op} {name}: {size} B")
+            if op == "dynamic-update-slice" and body.get(args[1], ("", 0))[1] >= nbytes:
+                found.append(f"{name} writes {body[args[1]][1]} B")
+            if comp in fused:
+                continue
+            if op == "dynamic-slice" and size >= nbytes:
+                found.append(f"{op} {name}: {size} B")
+            if op == "fusion" and size >= nbytes and root_op(callee) in (
+                "copy", "dynamic-slice", "transpose"
+            ):
+                found.append(f"fusion {name}: {size} B, a {root_op(callee)}")
+    return found
+
+
+def aliased_params(hlo: str) -> set:
+    """Entry parameter numbers the module's outputs alias."""
+    m = re.search(r"input_output_alias=\{(.*?)\}, entry", hlo)
+    return {int(p) for p in re.findall(r": \((\d+),", m.group(1))} if m else set()
+
+
+@pytest.mark.parametrize(
+    "arch,layers",
+    [("stablelm-1.6b", 24), ("internlm2-20b", 12)],
+    ids=["stablelm-1.6b", "internlm2-20b-stage12"],
+)
+def test_decode_step_updates_cache_in_place_on_v5e(arch, layers, topo, no_persistent_cache):
+    """The decode step as the chip benchmark serves it (one request, 2,080
+    cache slots) writes each layer's new key and value where the stacked
+    cache lies: no copy, re-lay or slice as large as one layer's K cache,
+    and the donated cache is the step's output."""
+    from repro.dist.step import make_serve_fns, param_specs
+    from repro.launch.mesh import make_host_mesh
+    from repro.models.registry import build_model
+
+    cfg = dataclasses.replace(get_config(arch), n_layers=layers)
+    model = build_model(cfg)
+    mesh = make_host_mesh(devices=topo.devices[:1])
+    max_len = 2080
+    _, decode, st_shapes, shards = make_serve_fns(model, mesh, max_len=max_len, global_batch=1)
+    place = lambda tree, sh: jax.tree.map(
+        lambda s, d: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=d), tree, sh
+    )
+    params = place(param_specs(model)[0], shards["params"])
+    state = place(st_shapes, shards["state"])
+    tok = jax.ShapeDtypeStruct((1, 1), jnp.int32, sharding=NamedSharding(mesh, P()))
+    hlo = decode.lower(params, tok, state).compile().as_text()
+
+    layer_k = max_len * cfg.n_kv_heads * cfg.head_dim * 2  # bf16, batch 1
+    assert cache_sized_moves(hlo, layer_k) == []
+
+    n_params = len(jax.tree.leaves(params)) + 1
+    entry = hlo[hlo.index("\nENTRY "):]
+    assert len(re.findall(r" parameter\(\d+\)", entry)) == n_params + len(jax.tree.leaves(state))
+    leaves = jax.tree_util.tree_flatten_with_path(state)[0]
+    cache_params = {
+        n_params + i for i, (path, leaf) in enumerate(leaves) if len(leaf.shape) >= 4
+    }
+    assert len(cache_params) == 2 and cache_params <= aliased_params(hlo)

@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.dist.sharding import cache_logical_axes
 from repro.models.common import ArchConfig, LayerSpec
 from repro.models.registry import (
     build_model,
@@ -79,7 +80,8 @@ def test_swa_ring_buffer_wraparound():
 
     # ring-cache decode (cache size == window == 16 < L)
     state = init_serve_state(m, B, max_len=64)
-    assert state["caches"][0]["k"].shape[2] == 16  # ring allocated at window
+    seq_axis = cache_logical_axes(cfg, 64)[0]["k"].index("kv_seq")
+    assert state["caches"][0]["k"].shape[seq_axis] == 16  # ring allocated at window
     lg, state = prefill(m, params, toks[:, :8], state)
     errs = [float(jnp.abs(lg - full[:, 7]).max())]
     for t in range(8, L):
@@ -182,3 +184,98 @@ def test_deep_model_is_not_chaotic_at_init(arch):
 
     a, b = logits(0.0), logits(1e-6)
     assert float(jnp.abs(a - b).max() / jnp.abs(a).max()) < 1e-4
+
+
+CACHE_KINDS = {
+    # kind: (arch, config changes, prompt length, decode steps)
+    "linear-mha": ("stablelm-1.6b", {}, 8, 6),
+    "linear-gqa": ("internlm2-20b", {}, 8, 6),
+    "swa-ring": ("mixtral-8x7b", {"window": 16}, 8, 14),  # 22 tokens wrap a 16-slot ring
+    "mla-latent": ("minicpm3-4b", {}, 8, 6),
+    "mamba-state": ("falcon-mamba-7b", {}, 8, 6),
+    "hybrid": ("jamba-v0.1-52b", {}, 8, 6),
+}
+
+
+@pytest.mark.parametrize("kind", list(CACHE_KINDS))
+def test_stacked_cache_written_in_place(kind):
+    """The serve step writes each layer's new rows into the stacked cache at
+    its layer's slot: after a prefill and N decode steps every cache equals a
+    reference that runs the layers one at a time, each on a cache of its own,
+    and stacks what they wrote (to float32 rounding: the reference's layers
+    run outside the scan, so XLA fuses them differently). Slots no token
+    reached, and the zero lanes that pad a head dim, still hold 0 (ring
+    positions -1)."""
+    from repro.models.transformer import apply_layer
+
+    arch, changes, n_prompt, n_steps = CACHE_KINDS[kind]
+    cfg = dataclasses.replace(get_config(arch).reduced(), **changes)
+    m = build_model(cfg)
+    params, _ = m.init(jax.random.key(0))
+    B, max_len = 2, 32
+    toks = jax.random.randint(jax.random.key(1), (B, n_prompt + n_steps), 0, cfg.vocab)
+
+    def one_layer(tree, g):
+        return jax.tree.map(lambda a: a[g : g + 1], tree)
+
+    def reference_step(ref, tokens, t):
+        """Layer after layer, each writing into its own single-slot cache."""
+        x = m.embed(params, tokens)
+        L = tokens.shape[1]
+        pos = t + jnp.broadcast_to(jnp.arange(L)[None], (B, L))
+        out = [[None] * cfg.n_groups for _ in cfg.layout]
+        for g in range(cfg.n_groups):
+            for j, spec in enumerate(cfg.layout):
+                p = jax.tree.map(lambda a: a[g], params["blocks"][j])
+                x, out[j][g], _ = apply_layer(
+                    p, cfg, spec, x, pos, ref[j][g], None, None, jnp.int32(0)
+                )
+        return out
+
+    def stacked(ref):
+        return [
+            jax.tree.map(lambda *a: jnp.concatenate(a), *per_layer) for per_layer in ref
+        ]
+
+    state = init_serve_state(m, B, max_len)
+    ref = [[one_layer(c, g) for g in range(cfg.n_groups)] for c in state["caches"]]
+    _, state = prefill(m, params, toks[:, :n_prompt], state)
+    ref = reference_step(ref, toks[:, :n_prompt], 0)
+    _assert_cache_state(cfg, state["caches"], stacked(ref), n_prompt, max_len)
+    for t in range(n_prompt, n_prompt + n_steps):
+        _, state = decode_step(m, params, toks[:, t : t + 1], state)
+        ref = reference_step(ref, toks[:, t : t + 1], t)
+    _assert_cache_state(cfg, state["caches"], stacked(ref), n_prompt + n_steps, max_len)
+
+
+def _assert_cache_state(cfg, caches, reference, written, max_len):
+    axes = cache_logical_axes(cfg, max_len)
+    for c, r, ax in zip(caches, reference, axes):
+        assert jax.tree.structure(c) == jax.tree.structure(r)
+        for name in c:
+            np.testing.assert_allclose(
+                np.asarray(c[name]), np.asarray(r[name]), rtol=2e-4, atol=1e-5,
+                err_msg=f"{cfg.name}: cache {name!r} differs from the layer-by-layer writes",
+            )
+        if "index" in c:
+            np.testing.assert_array_equal(np.asarray(c["index"]), written)
+        if "kv_seq" not in ax.get("k", ax.get("c_kv", ())):
+            continue  # a mamba state has no slots
+        for name in ("k", "v", "c_kv", "k_rope"):
+            if name not in c:
+                continue
+            a = np.moveaxis(np.asarray(c[name]), ax[name].index("kv_seq"), 1)
+            S = a.shape[1]
+            if written < S:  # slots no token reached
+                assert not a[:, written:].any(), f"{cfg.name}: {name} written past token {written}"
+            assert a[:, : min(written, S)].any(axis=tuple(range(2, a.ndim))).all()
+        if "k" in c:  # zero lanes padding the head dim
+            assert not np.asarray(c["k"])[..., cfg.head_dim :].any()
+            assert not np.asarray(c["v"])[..., cfg.head_dim :].any()
+        if "pos" in c:
+            pos = np.asarray(c["pos"])
+            S = pos.shape[-1]
+            want = np.full(S, -1)
+            for p in range(written):
+                want[p % S] = p
+            np.testing.assert_array_equal(pos, np.broadcast_to(want, pos.shape))
