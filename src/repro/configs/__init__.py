@@ -1,4 +1,4 @@
-"""Assigned-architecture registry: 10 archs x 4 input shapes = 40 cells.
+"""Assigned-architecture registry: 11 archs x 4 input shapes = 44 cells.
 
 Each architecture has its own module with the exact published config; this
 registry maps ``--arch <id>`` names to configs and defines the input-shape
@@ -20,6 +20,7 @@ from repro.models.common import ArchConfig
 
 _MODULES = {
     "jamba-v0.1-52b": "jamba_v0_1_52b",
+    "jamba2-mini": "jamba2_mini",
     "mixtral-8x7b": "mixtral_8x7b",
     "phi3.5-moe-42b": "phi3_5_moe_42b",
     "internlm2-20b": "internlm2_20b",

@@ -196,5 +196,7 @@ def cache_logical_axes(cfg: ArchConfig, max_len: int) -> list:
     out = []
     for spec in cfg.layout:
         tree = mamba_axes() if spec.mixer == "mamba" else attention_axes()
+        if spec.ffn == "moe":  # the routing record of a serving MoE layer
+            tree.update(route=(None, None), choice=("batch", None, None))
         out.append({k: ("layers",) + v for k, v in tree.items()})
     return out

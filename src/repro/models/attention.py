@@ -213,9 +213,9 @@ def _project_qkv(p: dict, cfg: ArchConfig, x: jax.Array, positions: jax.Array):
         q = q + p["bq"]
         k = k + p["bk"]
         v = v + p["bv"]
-    # RoPE on q/k (positions broadcast over heads)
-    q = apply_rope(q.swapaxes(1, 2), positions[:, None], cfg.rope_theta).swapaxes(1, 2)
-    k = apply_rope(k.swapaxes(1, 2), positions[:, None], cfg.rope_theta).swapaxes(1, 2)
+    if cfg.rope_theta:  # RoPE on q/k (positions broadcast over heads); 0: no positions
+        q = apply_rope(q.swapaxes(1, 2), positions[:, None], cfg.rope_theta).swapaxes(1, 2)
+        k = apply_rope(k.swapaxes(1, 2), positions[:, None], cfg.rope_theta).swapaxes(1, 2)
     return q, k, v
 
 

@@ -47,11 +47,14 @@ class ArchConfig:
     attention: str = "full"  # full | swa | mla
     window: int = 0  # SWA window (0 = unlimited)
     qkv_bias: bool = False
-    rope_theta: float = 1e4
+    rope_theta: float = 1e4  # 0: no positional encoding (Jamba's attention)
     # MoE
-    n_experts: int = 0
+    n_experts: int = 0  # the router's outputs
     top_k: int = 0
     capacity_factor: float = 1.25
+    experts_held: int = 0  # experts whose weights this device holds (0: all)
+    experts_first: int = 0  # id of the first of them; tokens routed elsewhere add nothing here
+    moe_renorm: bool = True  # top-k weights renormalised to sum to 1 (Mixtral); Jamba keeps softmax's
     # MLA (minicpm3-style)
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
@@ -62,6 +65,7 @@ class ArchConfig:
     ssm_state: int = 16
     ssm_conv: int = 4
     ssm_expand: int = 2
+    ssm_bcdt_norm: bool = False  # RMSNorm on dt, B and C after x_proj (Jamba's mixer)
     # encoder-decoder
     encoder_layers: int = 0  # 0 -> decoder-only
     cross_attention: bool = False
@@ -93,6 +97,10 @@ class ArchConfig:
         """Padded head count (TP-divisibility lever; pad wo rows are zero at
         init so padded heads contribute nothing)."""
         return self.n_heads + self.pad_heads
+
+    @property
+    def experts_here(self) -> int:
+        return self.experts_held or self.n_experts
 
     @property
     def d_inner(self) -> int:
@@ -144,11 +152,13 @@ class ArchConfig:
                 p += d * 2 * di + di * self.ssm_conv
                 p += di * (self.dt_rank + 2 * N) + self.dt_rank * di
                 p += di * N + di + di * d
+                if self.ssm_bcdt_norm:
+                    p += self.dt_rank + 2 * N
             if spec.ffn == "dense":
                 p += 3 * d * self.d_ff  # SwiGLU
             elif spec.ffn == "moe":
                 p += d * self.n_experts  # router
-                p += self.n_experts * 3 * d * self.d_ff
+                p += self.experts_here * 3 * d * self.d_ff
             p += 2 * d  # two norms
             total += p * self.n_groups
         if self.encoder_layers:
@@ -170,7 +180,7 @@ class ArchConfig:
             return self.n_params()
         d = self.d_model
         moe_layers = sum(1 for s in self.layout if s.ffn == "moe") * self.n_groups
-        inactive = moe_layers * (self.n_experts - self.top_k) * 3 * d * self.d_ff
+        inactive = moe_layers * (self.experts_here - self.top_k) * 3 * d * self.d_ff
         return int(self.n_params() - inactive)
 
     def reduced(self) -> "ArchConfig":
@@ -186,6 +196,7 @@ class ArchConfig:
             d_ff=128 if self.d_ff else 0,
             vocab=256,
             n_experts=min(self.n_experts, 4),
+            experts_held=min(self.experts_held, 2),
             top_k=min(self.top_k, 2),
             q_lora_rank=32 if self.q_lora_rank else 0,
             kv_lora_rank=16 if self.kv_lora_rank else 0,

@@ -19,7 +19,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from .common import ArchConfig, ParamBuilder, cache_layer, cache_write, shard
+from .common import ArchConfig, ParamBuilder, cache_layer, cache_write, rms_norm, shard
 
 
 def init_mamba(pb: ParamBuilder, cfg: ArchConfig) -> dict:
@@ -33,7 +33,7 @@ def init_mamba(pb: ParamBuilder, cfg: ArchConfig) -> dict:
         np.random.RandomState(0).uniform(np.log(1e-3), np.log(1e-1), size=(di,))
     ).astype(np.float32)
     dt_bias = dt + np.log1p(-np.exp(-dt))  # inverse softplus
-    return {
+    p = {
         "in_proj": pb.dense((d, 2 * di), ("embed", "inner")),
         "conv_w": pb.dense((k, di), (None, "inner"), scale=k**-0.5),
         "conv_b": pb.zeros((di,), ("inner",)),
@@ -44,6 +44,11 @@ def init_mamba(pb: ParamBuilder, cfg: ArchConfig) -> dict:
         "d_skip": pb.ones((di,), ("inner",)),
         "out_proj": pb.dense((di, d), ("inner", "embed")),
     }
+    if cfg.ssm_bcdt_norm:
+        p["dt_norm"] = pb.ones((r,), (None,))
+        p["b_norm"] = pb.ones((n,), (None,))
+        p["c_norm"] = pb.ones((n,), (None,))
+    return p
 
 
 def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
@@ -59,13 +64,31 @@ def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
 
 
 def _ssm_params(p: dict, cfg: ArchConfig, xc: jax.Array):
-    """xc: (B, L, Di) post-conv activations -> dt (f32), Bmat, Cmat."""
+    """xc: (B, L, Di) post-conv activations -> dt (f32), Bmat, Cmat. With
+    ``cfg.ssm_bcdt_norm`` the x_proj outputs dt (over dt_rank), B and C (over
+    d_state) are RMS-normalised first, each with its own weight (Jamba)."""
     r, n = cfg.dt_rank, cfg.ssm_state
     proj = jnp.einsum("bld,dr->blr", xc, p["x_proj"])  # (B,L,r+2n)
     dt_in, Bm, Cm = proj[..., :r], proj[..., r : r + n], proj[..., r + n :]
+    if cfg.ssm_bcdt_norm:
+        dt_in = rms_norm(dt_in, p["dt_norm"], cfg.norm_eps)
+        Bm = rms_norm(Bm, p["b_norm"], cfg.norm_eps)
+        Cm = rms_norm(Cm, p["c_norm"], cfg.norm_eps)
     dt = jnp.einsum("blr,rd->bld", dt_in, p["dt_proj"]).astype(jnp.float32)
     dt = jax.nn.softplus(dt + p["dt_bias"])  # (B,L,Di) f32
     return dt, Bm.astype(jnp.float32), Cm.astype(jnp.float32)
+
+
+# bytes of one batch row's float32 (chunk, Di, N) tile of the chunked scan:
+# 64 steps at Di 8192 and N 16, so a 2,048-token prefill of Jamba's width
+# fits beside one chip's share of its weights
+SCAN_TILE_BYTES = 32 << 20
+
+
+def scan_chunk(cfg: ArchConfig, L: int) -> int:
+    """Time steps per chunk of an L-step scan: as many as fit one batch
+    row's float32 (chunk, Di, N) tile in ``SCAN_TILE_BYTES``."""
+    return max(1, min(L, SCAN_TILE_BYTES // (cfg.d_inner * cfg.ssm_state * 4)))
 
 
 def selective_scan(
@@ -147,7 +170,7 @@ def mamba_block(
         xc = jax.nn.silu(_causal_conv(xr, p["conv_w"], p["conv_b"]))
         dt, Bm, Cm = _ssm_params(p, cfg, xc)
         scan = scan_impl or selective_scan
-        y, _ = scan(xc, dt, Bm, Cm, a, chunk_len=min(256, L))
+        y, _ = scan(xc, dt, Bm, Cm, a, chunk_len=scan_chunk(cfg, L))
         new_cache = None
     elif L == 1:
         # decode: single-token affine update
@@ -172,7 +195,7 @@ def mamba_block(
         xc = jax.nn.silu(acc + p["conv_b"])
         dt, Bm, Cm = _ssm_params(p, cfg, xc)
         scan = scan_impl or selective_scan
-        y, h_final = scan(xc, dt, Bm, Cm, a, h0=cache["h"], chunk_len=min(256, L))
+        y, h_final = scan(xc, dt, Bm, Cm, a, h0=cache["h"], chunk_len=scan_chunk(cfg, L))
         new_cache = {"h": h_final, "conv": conv_in[:, -(K - 1) :]}
 
     if new_cache is not None:  # the layer's small state replaced in its slot

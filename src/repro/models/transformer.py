@@ -86,6 +86,10 @@ def apply_layer(
     """Returns (x, new_cache, aux_loss)."""
     aux = jnp.zeros((), jnp.float32)
     kernels = kernels or {}
+    route = None  # an MoE layer's routing record rides in its layer's cache
+    if cache is not None and "route" in cache:
+        route = {k: cache[k] for k in ("route", "choice")}
+        cache = {k: v for k, v in cache.items() if k not in route}
     h = _rms(x, p["ln1"], cfg.norm_eps)
     if spec.mixer == "attention":
         if cfg.attention == "mla":
@@ -113,8 +117,13 @@ def apply_layer(
     if "ffn" in p:
         h = _rms(x, p["ln2"], cfg.norm_eps)
         if spec.ffn == "moe":
-            y, mo = moe_mod.moe_ffn(p["ffn"], cfg, h, gmm=kernels.get("moe_gmm"))
+            # serving (a cache) drops no routed row; training keeps capacity bins
+            y, mo = moe_mod.moe_ffn(
+                p["ffn"], cfg, h, gmm=kernels.get("moe_gmm"), drop_free=cache is not None
+            )
             aux = aux + mo["aux_loss"]
+            if route is not None:
+                new_cache = {**new_cache, **moe_mod.record_route(route, mo, layer, positions)}
         else:
             y = moe_mod.dense_ffn(p["ffn"], h)
         x = x + y
@@ -303,6 +312,8 @@ class Model:
                 c = attn.init_mla_cache(cfg, batch, max_len, dt)
             else:
                 c = attn.init_attention_cache(cfg, batch, max_len, dt)
+            if spec.ffn == "moe":
+                c = {**c, **moe_mod.init_route_state(cfg, batch, max_len)}
             return jax.tree.map(
                 lambda a: jnp.broadcast_to(a[None], (cfg.n_groups,) + a.shape), c
             )

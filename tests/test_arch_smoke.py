@@ -114,6 +114,7 @@ def test_param_counts_are_sane():
     """Full-config analytic parameter counts land near the published sizes."""
     expected = {
         "jamba-v0.1-52b": (45e9, 60e9),
+        "jamba2-mini": (50e9, 53e9),  # 51.57 B published as 52B
         "mixtral-8x7b": (42e9, 50e9),
         "phi3.5-moe-42b": (38e9, 46e9),
         "internlm2-20b": (17e9, 23e9),

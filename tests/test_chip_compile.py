@@ -226,3 +226,36 @@ def test_decode_step_updates_cache_in_place_on_v5e(arch, layers, topo, no_persis
         n_params + i for i, (path, leaf) in enumerate(leaves) if len(leaf.shape) >= 4
     }
     assert len(cache_params) == 2 and cache_params <= aliased_params(hlo)
+
+
+def test_jamba2_mini_serve_steps_fit_one_v5e(topo, no_persistent_cache):
+    """Jamba2-Mini cut as the chip benchmark serves it (one 8-layer period,
+    8 of 16 experts held, 8,192 vocabulary rows, 2,080 slots): the prefill of
+    a 2,048-token prompt and the decode step compile for one v5e, each with
+    its arguments (14.39 GB of weights) and temporaries inside 16 GB, and
+    the decode step writes the attention layer's KV cache in place."""
+    from repro.dist.step import make_serve_fns, param_specs
+    from repro.launch.mesh import make_host_mesh
+    from repro.models.registry import build_model
+
+    cfg = dataclasses.replace(get_config("jamba2-mini"), n_layers=8, experts_held=8, vocab=8192)
+    model = build_model(cfg)
+    mesh = make_host_mesh(devices=topo.devices[:1])
+    max_len = 2080
+    prefill, decode, st_shapes, shards = make_serve_fns(
+        model, mesh, max_len=max_len, global_batch=1
+    )
+    place = lambda tree, sh: jax.tree.map(
+        lambda s, d: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=d), tree, sh
+    )
+    params = place(param_specs(model)[0], shards["params"])
+    state = place(st_shapes, shards["state"])
+    rep = NamedSharding(mesh, P())
+    for n in (2048, 1):
+        step = prefill if n > 1 else decode
+        compiled = step.lower(params, jax.ShapeDtypeStruct((1, n), jnp.int32, sharding=rep), state).compile()
+        mem = compiled.memory_analysis()
+        assert mem.argument_size_in_bytes > 14.3e9
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9, n
+    layer_k = max_len * cfg.n_kv_heads * cfg.head_dim * 2
+    assert cache_sized_moves(compiled.as_text(), layer_k) == []
