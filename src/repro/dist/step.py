@@ -236,6 +236,29 @@ def make_train_step(
 # ---------------------------------------------------------------------------
 
 
+def serve_kernels(mesh, rules: dict) -> Optional[dict]:
+    """The kernels of ``kernels.ops.kernel_set`` that the serve steps hand
+    the model on ``mesh``: on a TPU its selective scan, compiled (the
+    platform is the mesh's, so a compile for a described chip from a CPU
+    process takes it too), one shard per device over the batch and inner
+    channels the rules place; elsewhere none, and the models take their
+    jnp paths."""
+    if mesh.devices.flat[0].platform != "tpu":
+        return None
+    from repro.kernels.ops import kernel_set
+
+    batch, inner = rules.get("batch"), rules.get("inner")
+    seq, rows, state = P(batch, None, inner), P(batch, None, None), P(batch, inner, None)
+    scan = jax.shard_map(
+        functools.partial(kernel_set(True)["mamba_scan"], interpret=False),
+        mesh=mesh,
+        in_specs=(seq, seq, rows, rows, P(inner, None), state),
+        out_specs=(seq, state),
+        check_vma=False,
+    )
+    return {"mamba_scan": scan}
+
+
 def make_serve_fns(
     model,
     mesh,
@@ -254,7 +277,7 @@ def make_serve_fns(
 
     Shardings are applied as in-function constraints (not ``in_shardings``)
     so callers may thread extra state entries (e.g. encoder "memory")
-    through untouched.
+    through untouched. The steps run ``serve_kernels(mesh, rules)``.
     """
     cfg = model.cfg
     rules = dict(rules) if rules is not None else make_rules(cfg, mesh, "serve", global_batch)
@@ -269,6 +292,7 @@ def make_serve_fns(
     logits_shard = NamedSharding(
         mesh, pspec_for_axes(("batch", "vocab"), (global_batch, cfg.vocab), rules, mesh)
     )
+    kernels = serve_kernels(mesh, rules)
 
     def _constrain(tree_, shard_tree):
         return jax.tree.map(jax.lax.with_sharding_constraint, tree_, shard_tree)
@@ -278,7 +302,7 @@ def make_serve_fns(
         state = {**state, "caches": _constrain(state["caches"], cache_shard)}
         with axis_rules(rules, mesh):
             logits, new_state = _prefill(
-                model, params, tokens, state, frames=frames, prefix=prefix
+                model, params, tokens, state, frames=frames, prefix=prefix, kernels=kernels
             )
         new_state = {**new_state, "caches": _constrain(new_state["caches"], cache_shard)}
         return jax.lax.with_sharding_constraint(logits, logits_shard), new_state
@@ -287,7 +311,7 @@ def make_serve_fns(
         params = _constrain(params, param_shard)
         state = {**state, "caches": _constrain(state["caches"], cache_shard)}
         with axis_rules(rules, mesh):
-            logits, new_state = _decode_step(model, params, tokens, state)
+            logits, new_state = _decode_step(model, params, tokens, state, kernels=kernels)
         new_state = {**new_state, "caches": _constrain(new_state["caches"], cache_shard)}
         return jax.lax.with_sharding_constraint(logits, logits_shard), new_state
 

@@ -6,7 +6,9 @@ Train/prefill path: *chunked* selective scan — time is split into chunks of
 the (B, Di, N) state is carried across chunks with ``lax.scan``. The
 (B, L, Di, N) discretized tensors therefore only ever exist one chunk at a
 time — the same blocking the ``repro.kernels.mamba_scan`` Pallas kernel uses
-to keep the working set in VMEM.
+to keep the working set in VMEM. A serving prefill on a TPU runs that
+kernel instead (``scan_impl``, handed down by ``dist.step.make_serve_fns``);
+the training step hands none and keeps the jnp scan, which differentiates.
 
 Decode path: O(1) per token — one affine state update plus a depthwise-conv
 ring window.
@@ -14,6 +16,7 @@ ring window.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -194,8 +197,8 @@ def mamba_block(
             acc = acc + p["conv_w"][k] * jax.lax.slice_in_dim(conv_in, k, k + L, axis=1)
         xc = jax.nn.silu(acc + p["conv_b"])
         dt, Bm, Cm = _ssm_params(p, cfg, xc)
-        scan = scan_impl or selective_scan
-        y, h_final = scan(xc, dt, Bm, Cm, a, h0=cache["h"], chunk_len=scan_chunk(cfg, L))
+        scan = scan_impl or functools.partial(selective_scan, chunk_len=scan_chunk(cfg, L))
+        y, h_final = scan(xc, dt, Bm, Cm, a, cache["h"])
         new_cache = {"h": h_final, "conv": conv_in[:, -(K - 1) :]}
 
     if new_cache is not None:  # the layer's small state replaced in its slot

@@ -233,7 +233,10 @@ def test_jamba2_mini_serve_steps_fit_one_v5e(topo, no_persistent_cache):
     8 of 16 experts held, 8,192 vocabulary rows, 2,080 slots): the prefill of
     a 2,048-token prompt and the decode step compile for one v5e, each with
     its arguments (14.39 GB of weights) and temporaries inside 16 GB, and
-    the decode step writes the attention layer's KV cache in place."""
+    the decode step writes the attention layer's KV cache in place. The
+    prefill's seven selective scans are compiled Pallas kernels under the
+    ``pallas_mamba_scan`` scope the benchmark reads, with no instruction
+    left under the jnp scan's; the decode step holds no scan kernel."""
     from repro.dist.step import make_serve_fns, param_specs
     from repro.launch.mesh import make_host_mesh
     from repro.models.registry import build_model
@@ -257,5 +260,34 @@ def test_jamba2_mini_serve_steps_fit_one_v5e(topo, no_persistent_cache):
         mem = compiled.memory_analysis()
         assert mem.argument_size_in_bytes > 14.3e9
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9, n
+        hlo = compiled.as_text()
+        scans = [line for line in hlo.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line and "pallas_mamba_scan" in line]
+        assert len(scans) == (7 if n > 1 else 0), n
+        assert "jnp_mamba_scan" not in hlo
     layer_k = max_len * cfg.n_kv_heads * cfg.head_dim * 2
     assert cache_sized_moves(compiled.as_text(), layer_k) == []
+
+
+@pytest.mark.parametrize("model_axis,batch", [(1, 4), (4, 1), (2, 2)], ids=["data4", "model4", "data2-model2"])
+def test_mamba_prefill_runs_one_scan_kernel_per_shard_on_four_v5e(model_axis, batch, topo, no_persistent_cache):
+    """On a four-chip mesh the serving prefill's scan kernel runs on each
+    device's shard of the batch and inner channels (a Mosaic kernel cannot
+    be partitioned by the compiler): it compiles, and the compiler gathers
+    no operand of it."""
+    from repro.dist.step import make_serve_fns, param_specs
+    from repro.launch.mesh import make_host_mesh
+    from repro.models.registry import build_model
+
+    model = build_model(get_config("falcon-mamba-7b").reduced())
+    mesh = make_host_mesh(model=model_axis, devices=topo.devices)
+    prefill, _, st_shapes, shards = make_serve_fns(model, mesh, max_len=256, global_batch=batch)
+    place = lambda tree, sh: jax.tree.map(
+        lambda s, d: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=d), tree, sh
+    )
+    params = place(param_specs(model)[0], shards["params"])
+    state = place(st_shapes, shards["state"])
+    tokens = jax.ShapeDtypeStruct((batch, 128), jnp.int32, sharding=NamedSharding(mesh, P()))
+    hlo = prefill.lower(params, tokens, state).compile().as_text()
+    assert "pallas_mamba_scan" in hlo and 'custom_call_target="tpu_custom_call"' in hlo
+    assert re.search(r" all-gather(-start)?\(", hlo) is None

@@ -201,3 +201,76 @@ def test_dt_b_c_norms_only_where_the_mixer_has_them(arch, norms):
         for name in ("dt_norm", "b_norm", "c_norm"):
             y2, _ = mamba_block({**p, name: 2 * p[name]}, cfg, x, None)
             assert not bool(jnp.allclose(y, y2))
+
+
+@pytest.mark.parametrize("case", ["fresh_ragged_prefill", "prefill_into_state", "training_forward"])
+def test_mamba_block_takes_the_scan_kernel_only_when_serving(case):
+    """A serving prefill's selective scan through the Pallas kernel
+    (interpreted here, in chunks of 8 steps and two blocks of 64 channels,
+    so the 13-step prompt pads its last chunk) gives the jnp chunked
+    scan's output and writes the same scan state and conv window into the
+    layer's cache slot, touching no other slot: into a fresh state, and
+    into one that carries ``h`` and the conv window in. The train step
+    hands the model no kernel, so its forward (no cache) takes the jnp
+    scan, and differentiates."""
+    from repro.kernels.mamba_scan import mamba_scan
+    from repro.models import mamba
+    from repro.models.common import split_tree
+
+    cfg = _tiny()
+    if case == "training_forward":
+        from repro.dist import step
+        from repro.launch.mesh import make_host_mesh
+        from repro.optim import adamw_init, constant_lr
+
+        handed, scans = [], []
+        real_loss, real_scan = step.train_loss, mamba.selective_scan
+
+        def loss_spy(*args, **kw):
+            handed.append(kw.get("kernels"))
+            return real_loss(*args, **kw)
+
+        def scan_spy(*args, **kw):
+            scans.append(args[0].shape)
+            return real_scan(*args, **kw)
+
+        model = build_model(cfg)
+        params = _params(model)
+        toks = jax.random.randint(jax.random.key(2), (2, 12), 0, cfg.vocab)
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(step, "train_loss", loss_spy)
+            mp.setattr(mamba, "selective_scan", scan_spy)
+            train, *_ = step.make_train_step(model, make_host_mesh(), constant_lr(1e-3), global_batch=2)
+            state = {"params": params, "opt": adamw_init(params), "step": jnp.int32(0)}
+            _, metrics = train(state, {"tokens": toks, "labels": toks})
+        assert handed == [None]
+        assert scans and all(shape == (2, 12, cfg.d_inner) for shape in scans)
+        assert bool(jnp.isfinite(metrics["loss"])) and float(metrics["grad_norm"]) > 0
+        return
+
+    p, _ = split_tree(mamba.init_mamba(ParamBuilder(jax.random.key(4), jnp.float32), cfg))
+    B, L, G, layer = 2, 13, 3, jnp.int32(1)
+    keys = jax.random.split(jax.random.key(5), 3)
+    x = jax.random.normal(keys[0], (B, L, cfg.d_model), jnp.float32)
+    cache = mamba.init_mamba_cache(cfg, B, jnp.float32)
+    cache = {k: jnp.zeros((G,) + c.shape, c.dtype) for k, c in cache.items()}
+    if case == "prefill_into_state":
+        cache = {k: 0.5 * jax.random.normal(kk, c.shape, c.dtype)
+                 for (k, c), kk in zip(cache.items(), keys[1:])}
+    calls = []
+
+    def kernel(*args):
+        calls.append(args[0].shape)
+        return mamba_scan(*args, chunk_len=8, d_block=64, interpret=True)
+
+    assert cfg.d_inner == 2 * 64
+    pos = jnp.broadcast_to(jnp.arange(L)[None], (B, L))
+    want, want_cache = mamba.mamba_block(p, cfg, x, pos, cache, layer=layer)
+    got, got_cache = mamba.mamba_block(p, cfg, x, pos, cache, scan_impl=kernel, layer=layer)
+    assert calls == [(B, L, cfg.d_inner)]
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+    for k in ("h", "conv"):
+        np.testing.assert_allclose(got_cache[k], want_cache[k], rtol=1e-4, atol=1e-5)
+        others = np.delete(np.asarray(got_cache[k]), 1, axis=0)
+        np.testing.assert_array_equal(others, np.delete(np.asarray(cache[k]), 1, axis=0))
+    assert float(jnp.abs(got_cache["h"][1] - cache["h"][1]).max()) > 0.01
