@@ -123,13 +123,12 @@ def make_rules(
         "q_lora": None,
         "kv_lora": None,
         "vocab": model if cfg.vocab % tp == 0 else None,
-        "heads": model if cfg.n_heads_eff % tp == 0 else None,
+        "heads": model if cfg.n_heads % tp == 0 else None,
         "kv_heads": model if cfg.n_kv_heads % tp == 0 else None,
         "inner": model if cfg.d_inner % tp == 0 else None,
         "batch": (
             (data_axes if len(data_axes) > 1 else data_axes[0]) if batch_ok else None
         ),
-        "moe_group": None,
     }
 
     # MoE FFN: expert parallelism when the expert count divides the model
@@ -139,8 +138,6 @@ def make_rules(
     else:
         rules["experts"] = None
         rules["mlp"] = model if (cfg.d_ff and cfg.d_ff % tp == 0) else None
-    if cfg.moe_groups and "data" in mesh_shape:
-        rules["moe_group"] = "data"
 
     # FSDP (ZeRO-3 posture) is a throughput lever: train only.
     rules["embed"] = "data" if (mode == "train" and "data" in mesh_shape) else None

@@ -15,9 +15,7 @@ to ``ref.reference_hash_tree`` (pure jnp) and to the numpy definition in
 Roofline audit (analytic, like the other kernels): the kernel reads
 ``4 * n_words`` bytes once and writes a 12-byte state — arithmetic
 intensity ~= 3 ops / 4 bytes, i.e. firmly **memory-bound**; the ceiling is
-DRAM bandwidth, not compute. ``B14_hotpath_throughput`` reports achieved
-bytes/s against the host's memcpy roofline (the numpy path reaches
-~10x sha256 on the bench host; sha256 is compute-bound at ~1 GiB/s).
+DRAM bandwidth, not compute.
 
 Contract: input is a 1-D uint32 word array whose length is a multiple of
 ``TREE_BLOCK_WORDS * CHUNK_BLOCKS`` (callers slice the chunk-aligned bulk

@@ -7,14 +7,15 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 This is Koalja wireframing (§III.K) applied to the TPU program: ghost batches
 (ShapeDtypeStructs) are pushed through the full distributed train/serve step —
 ``jit(...).lower(...).compile()`` — proving the sharded wiring (collective
-schedule, per-device memory, FLOPs) without allocating a byte of real data.
+schedule, per-device memory) without allocating a byte of real data.
 
   python -m repro.launch.dryrun --arch mixtral-8x7b --shape train_4k
   python -m repro.launch.dryrun --all                # 40-cell baseline table
   python -m repro.launch.dryrun --all --multipod     # 2-pod (512 chip) pass
 
-Results append to benchmarks/results/dryrun/<mesh>/<arch>__<shape>.json; the
-roofline table in EXPERIMENTS.md is generated from those records.
+Each cell's record (device count, compile time, per-device state and XLA's
+memory analysis) is written to
+benchmarks/results/dryrun/<mesh>/<arch>__<shape>.json.
 """
 
 import argparse
@@ -38,7 +39,6 @@ from repro.launch.device import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.models.registry import build_model
 from repro.optim import cosine_warmup
-from repro.roofline import analyze_compiled
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..", "benchmarks", "results", "dryrun")
 
@@ -59,7 +59,7 @@ def dryrun_cell(
     save: bool = True,
     tag: str = "",
 ):
-    """Lower+compile one cell; returns the roofline record (dict)."""
+    """Lower+compile one cell; returns its record (dict)."""
     import dataclasses
 
     cfg = get_config(arch)
@@ -153,23 +153,15 @@ def dryrun_cell(
             st_shapes["caches"], shards["state"]["caches"]
         )
 
-    report = analyze_compiled(
-        compiled,
-        arch=arch,
-        shape=shape,
-        mesh_name=_mesh_name(multi_pod),
-        n_devices=n_dev,
-        kind=spec.kind,
-        cfg=cfg,
-        seq_len=spec.seq_len,
-        global_batch=spec.global_batch,
-        mesh_shape=dict(mesh.shape),
-        rules=rules,
-    )
-    rec = report.to_record()
-    rec["roofline_frac"] = report.roofline_frac
-    rec["compile_seconds"] = compile_s
-    rec["state_gb_per_device"] = state_gb
+    rec = {
+        "arch": arch,
+        "shape": shape,
+        "mesh": _mesh_name(multi_pod),
+        "n_devices": n_dev,
+        "kind": spec.kind,
+        "compile_seconds": compile_s,
+        "state_gb_per_device": state_gb,
+    }
     if state_gb > 16.0:
         print(f"[WARN] {arch} x {shape}: state {state_gb:.1f} GB/device exceeds v5e HBM")
     try:
@@ -189,17 +181,9 @@ def dryrun_cell(
         rec["memory_analysis"] = None
 
     if verbose:
-        raw = (
-            f" memory_raw={report.t_memory_raw*1e3:.2f}ms"
-            if report.t_memory_raw and abs(report.t_memory_raw - report.t_memory) > 1e-9
-            else ""
-        )
         print(
             f"[OK] {arch} x {shape} ({_mesh_name(multi_pod)}): "
-            f"compute={report.t_compute*1e3:.2f}ms memory={report.t_memory*1e3:.2f}ms{raw} "
-            f"collective={report.t_collective*1e3:.2f}ms -> {report.bottleneck}-bound; "
-            f"useful/HLO={report.useful_flops_frac:.3f} roofline_frac={report.roofline_frac:.3f} "
-            f"(compile {compile_s:.1f}s)"
+            f"state {state_gb:.2f} GB/device (compile {compile_s:.1f}s)"
         )
     if save:
         _save(rec, multi_pod, arch, shape, tag)
@@ -234,7 +218,7 @@ def main(argv=None):
     ap.add_argument("--tag", default="")
     ap.add_argument(
         "--set", action="append", default=[],
-        help="ArchConfig override, e.g. --set causal_skip=True --set block_kv=1024",
+        help="ArchConfig override, e.g. --set remat=full --set block_kv=1024",
     )
     args = ap.parse_args(argv)
 

@@ -7,7 +7,8 @@ Two execution paths share one math definition:
     compiled HLO never materializes an (Lq, Lkv) score matrix, so the memory
     analysis is honest about what a fused kernel would use.
   - ``repro.kernels.flash_attention`` — the Pallas TPU kernel with the same
-    blocking scheme (HBM->VMEM streaming). Selected by ``cfg.use_pallas``.
+    blocking scheme (HBM->VMEM streaming). No model step calls it yet; it is
+    compiled for v5e and checked against ``repro.kernels.ref``.
 
 GQA is computed grouped — KV heads are never repeated in memory: scores are
 einsummed as (B, KVH, Gq, Lq, Lkv) against unexpanded KV.
@@ -62,7 +63,6 @@ def blocked_attention(
     window: int = 0,
     block_q: int = 512,
     block_kv: int = 512,
-    causal_skip: bool = False,  # hillclimb lever: unrolled growing-window
 ) -> jax.Array:
     """Online-softmax attention, O(block) memory. Returns (B, Lq, H, Dv).
 
@@ -70,21 +70,17 @@ def blocked_attention(
     with jax.named_scope("jnp_attention"):
         return _blocked_attention(
             q, k, v, causal=causal, window=window,
-            block_q=block_q, block_kv=block_kv, causal_skip=causal_skip,
+            block_q=block_q, block_kv=block_kv,
         )
 
 
-def _blocked_attention(q, k, v, *, causal, window, block_q, block_kv, causal_skip):
+def _blocked_attention(q, k, v, *, causal, window, block_q, block_kv):
     B, Lq, H, Dh = q.shape
     Lk, KVH = k.shape[1], k.shape[2]
     Dv = v.shape[-1]
     Gq = H // KVH
     qg = q.reshape(B, Lq, KVH, Gq, Dh)
     scale = Dh**-0.5
-
-    if causal_skip and causal and Lq == Lk and Lq % block_q == 0:
-        out = _causal_skip_attention(qg, k, v, scale, block_q, block_kv, window)
-        return out.reshape(B, Lq, H, Dv).astype(q.dtype)
 
     block_kv = min(block_kv, Lk)
     nkv = -(-Lk // block_kv)
@@ -146,58 +142,23 @@ def _blocked_attention(q, k, v, *, causal, window, block_q, block_kv, causal_ski
     return out.reshape(B, Lq, H, Dv).astype(q.dtype)
 
 
-def _causal_skip_attention(qg, k, v, scale, block_q, block_kv, window):
-    """Beyond-baseline lever: unrolled Python loop over Q blocks, each slicing
-    only the causally-visible KV prefix — compiled FLOPs ~ N^2/2 instead of
-    N^2 (the masked-full baseline). SWA additionally drops the out-of-window
-    prefix so compiled FLOPs ~ N*W."""
-    B, Lq, KVH, Gq, Dh = qg.shape
-    nq = Lq // block_q
-    outs = []
-    for i in range(nq):
-        qblk = jax.lax.slice_in_dim(qg, i * block_q, (i + 1) * block_q, axis=1)
-        lo = 0
-        if window > 0:
-            # earliest K any q-row in this block can see: q_lo - window + 1
-            lo = max(0, i * block_q - window + 1)
-            lo = (lo // block_kv) * block_kv  # block-align downwards
-        hi = (i + 1) * block_q
-        kblk = jax.lax.slice_in_dim(k, lo, hi, axis=1)
-        vblk = jax.lax.slice_in_dim(v, lo, hi, axis=1)
-        s = _gqa_scores(qblk, kblk) * scale
-        qpos = i * block_q + jnp.arange(block_q)
-        kpos = lo + jnp.arange(hi - lo)
-        s = s + _mask_bias(qpos, kpos, True, window)
-        p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhgqk,bkhd->bhgqd", p, vblk, preferred_element_type=jnp.float32)
-        outs.append(o.transpose(0, 3, 1, 2, 4))
-    return jnp.concatenate(outs, axis=1)
-
-
 # ---------------------------------------------------------------------------
 # Standard (GQA / SWA / QKV-bias) attention block
 # ---------------------------------------------------------------------------
 
 
-def _zero_pad_rows(pair, n_real: int):
-    w, axes = pair
-    return w.at[n_real:].set(0), axes
-
-
 def init_attention(pb: ParamBuilder, cfg: ArchConfig) -> dict:
-    d, H, KVH, Dh = cfg.d_model, cfg.n_heads_eff, cfg.n_kv_heads, cfg.head_dim
-    assert H % KVH == 0, f"padded heads {H} must stay a multiple of kv={KVH}"
+    d, H, KVH, Dh = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    assert H % KVH == 0, f"heads {H} must be a multiple of kv={KVH}"
     # 3-D weights name their fan-in: the contracted dims, not shape[-2]
     p = {
         "wq": pb.dense((d, H, Dh), ("embed", "heads", "head_dim"), scale=d**-0.5),
         "wk": pb.dense((d, KVH, Dh), ("embed", "kv_heads", "head_dim"), scale=d**-0.5),
         "wv": pb.dense((d, KVH, Dh), ("embed", "kv_heads", "head_dim"), scale=d**-0.5),
         "wo": pb.dense(
-            (H, Dh, d), ("heads", "head_dim", "embed"), scale=(cfg.n_heads * Dh) ** -0.5
+            (H, Dh, d), ("heads", "head_dim", "embed"), scale=(H * Dh) ** -0.5
         ),
     }
-    if cfg.pad_heads:
-        p["wo"] = _zero_pad_rows(p["wo"], cfg.n_heads)
     if cfg.qkv_bias:
         p["bq"] = pb.zeros((H, Dh), ("heads", "head_dim"))
         p["bk"] = pb.zeros((KVH, Dh), ("kv_heads", "head_dim"))
@@ -255,7 +216,6 @@ def attention_block(
             window=cfg.window if cfg.attention == "swa" else 0,
             block_q=cfg.block_q,
             block_kv=cfg.block_kv,
-            causal_skip=cfg.causal_skip,
         )
         new_cache = None
     else:
@@ -296,7 +256,6 @@ def attention_block(
                     causal=True,
                     window=cfg.window if cfg.attention == "swa" else 0,
                     block_q=cfg.block_q, block_kv=cfg.block_kv,
-                    causal_skip=cfg.causal_skip,
                 )
             else:
                 valid = jnp.arange(S)[None, :] < total
@@ -359,7 +318,7 @@ def kv_cache_layout(cfg: ArchConfig) -> tuple:
       KV head, which XLA lays out heads-major, ``(B, KVH, S, Dp)``; with one
       (MHA) they are a multiply-reduce, laid out slot-major, ``(B, S, KVH, Dp)``.
     """
-    heads_major = cfg.n_heads_eff // cfg.n_kv_heads > 1
+    heads_major = cfg.n_heads // cfg.n_kv_heads > 1
     return heads_major, -(-cfg.head_dim // LANES) * LANES
 
 
@@ -390,7 +349,7 @@ def init_attention_cache(cfg: ArchConfig, batch: int, max_len: int, dtype) -> di
 
 
 def init_mla(pb: ParamBuilder, cfg: ArchConfig) -> dict:
-    d, H = cfg.d_model, cfg.n_heads_eff
+    d, H = cfg.d_model, cfg.n_heads
     qr, kvr = cfg.q_lora_rank, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
     p = {
@@ -402,11 +361,9 @@ def init_mla(pb: ParamBuilder, cfg: ArchConfig) -> dict:
         "wk_b": pb.dense((kvr, H, dn), ("kv_lora", "heads", "head_dim"), scale=kvr**-0.5),
         "wv_b": pb.dense((kvr, H, dv), ("kv_lora", "heads", "head_dim"), scale=kvr**-0.5),
         "wo": pb.dense(
-            (H, dv, d), ("heads", "head_dim", "embed"), scale=(cfg.n_heads * dv) ** -0.5
+            (H, dv, d), ("heads", "head_dim", "embed"), scale=(H * dv) ** -0.5
         ),
     }
-    if cfg.pad_heads:
-        p["wo"] = _zero_pad_rows(p["wo"], cfg.n_heads)
     return p
 
 
@@ -435,7 +392,7 @@ def mla_block(
     if cache is None:
         # train / prefill: reconstitute per-head K,V once and run blocked
         # attention (scores never materialized at (L, L)).
-        H = cfg.n_heads_eff
+        H = cfg.n_heads
         k_nope = jnp.einsum("blr,rhk->blhk", c_kv, p["wk_b"])  # (B,L,H,dn)
         k_full = jnp.concatenate(
             [k_nope, jnp.broadcast_to(k_rope[:, :, None], (B, L, H, dr))], axis=-1
@@ -445,7 +402,6 @@ def mla_block(
         o = blocked_attention(
             q_full, k_full, v_full, causal=True,
             block_q=cfg.block_q, block_kv=cfg.block_kv,
-            causal_skip=cfg.causal_skip,
         )
         y = jnp.einsum("blhk,hkd->bld", o, p["wo"])
         return y, None
@@ -465,7 +421,7 @@ def mla_block(
         # prefill into the latent cache: reconstitute per-head K/V from the
         # cached latents and run blocked attention (absorbed scores would
         # materialize (L, S) — fine for decode, catastrophic for prefill).
-        H = cfg.n_heads_eff
+        H = cfg.n_heads
         k_nope = jnp.einsum("bsr,rhk->bshk", c_kv, p["wk_b"])
         k_full = jnp.concatenate(
             [k_nope, jnp.broadcast_to(k_rope[:, :, None], (B, S, H, dr))], axis=-1
@@ -475,7 +431,6 @@ def mla_block(
         o = blocked_attention(
             q_full, k_full, v_full, causal=True,
             block_q=cfg.block_q, block_kv=cfg.block_kv,
-            causal_skip=cfg.causal_skip,
         )
         y = jnp.einsum("blhk,hkd->bld", o, p["wo"])
         return y, new_cache

@@ -76,27 +76,16 @@ class ArchConfig:
     dtype: str = "bfloat16"
     tie_embeddings: bool = False
     norm_eps: float = 1e-5
-    # runtime knobs (hillclimb levers; not architecture)
+    # runtime knobs (not architecture)
     remat: str = "block"  # none | block | full
     block_q: int = 512
     block_kv: int = 512
-    causal_skip: bool = False  # unrolled growing-window causal attention
-    moe_groups: int = 0  # >0: group-local MoE dispatch (GShard groups = data shards)
-    pad_heads: int = 0  # pad attention heads for TP divisibility (zero wo rows)
-    moe_block_tokens: int = 0  # 0 = no token chunking in MoE
     moe_exact_tokens: int = 512  # decode/smoke-scale calls dispatch drop-free
-    use_pallas: bool = False  # TPU path; CPU tests use jnp references
 
     # -- derived -----------------------------------------------------------
     @property
     def head_dim(self) -> int:
         return self.d_head or (self.d_model // self.n_heads)
-
-    @property
-    def n_heads_eff(self) -> int:
-        """Padded head count (TP-divisibility lever; pad wo rows are zero at
-        init so padded heads contribute nothing)."""
-        return self.n_heads + self.pad_heads
 
     @property
     def experts_here(self) -> int:

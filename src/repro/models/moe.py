@@ -65,7 +65,7 @@ ROUTE_COUNTERS = ("held", "absent", "dropped", "hit_experts")
 
 
 def _dispatch(xf, gate_e, K, E, C):
-    """Sort-based dispatch for one token group.
+    """Sort-based dispatch of one call's tokens.
 
     xf: (T, D); gate_e: (T, K), ids >= E for experts not held here. Returns
     (xe (E, C, D), slot_by_flat (T*K,), keep_count) where slot E*C is the
@@ -111,14 +111,7 @@ def moe_ffn(
     The router scores all ``cfg.n_experts``; this layer holds
     ``cfg.experts_here`` of them, from ``cfg.experts_first``, and computes
     their part of the result: a token's slot on an absent expert adds
-    nothing (expert parallelism's share on one device, without its exchange).
-
-    ``cfg.moe_groups > 1`` enables GShard-style group-local dispatch: tokens
-    split into G groups aligned with the data shards, each group sorted and
-    capacity-binned locally, so the dispatch scatter never crosses the data
-    axis and per-device gemm work is 1/G of the global capacity (the baseline
-    global sort makes every device touch every token when experts cannot
-    shard — e.g. mixtral's 8 experts on a 16-way model axis)."""
+    nothing (expert parallelism's share on one device, without its exchange)."""
     with jax.named_scope("moe_layer"):
         return _moe_ffn(p, cfg, x, gmm, drop_free)
 
@@ -154,44 +147,30 @@ def _moe_ffn(p, cfg, x, gmm, drop_free):
         y = h[back].reshape(T, K, D)
         kept = hits.sum()
     else:
-        G = max(1, cfg.moe_groups)
-        if T % G:
-            G = 1
-        Tg = T // G
-        C = expert_capacity(Tg, cfg)
+        C = expert_capacity(T, cfg)
 
-        # 2. dispatch (per group, vmapped; G=1 == the global baseline)
-        xg = xf.reshape(G, Tg, D)
-        eg = local_e.reshape(G, Tg, K)
-        xe, slot_by_flat, kept = jax.vmap(
-            lambda xx, ee: _dispatch(xx, ee, K, Eh, C)
-        )(xg, eg)  # xe: (G, Eh, C, D)
-        xe = shard(xe, "moe_group", "experts", None, None)
+        # 2. dispatch
+        xe, slot_by_flat, kept = _dispatch(xf, local_e, K, Eh, C)  # xe: (Eh, C, D)
+        xe = shard(xe, "experts", None, None)
 
         # 3. grouped SwiGLU — the moe_gmm hook
-        if gmm is not None and G == 1:
+        if gmm is not None:
             with jax.named_scope("pallas_moe_gmm"):
-                h = gmm(xe[0], p["w_gate"], p["w_up"], p["w_down"])[None]
+                h = gmm(xe, p["w_gate"], p["w_up"], p["w_down"])
         else:
             with jax.named_scope("jnp_moe_gmm"):
-                g = jnp.einsum("gecd,edf->gecf", xe, p["w_gate"])
-                u = jnp.einsum("gecd,edf->gecf", xe, p["w_up"])
-                h = jnp.einsum("gecf,efd->gecd", jax.nn.silu(g) * u, p["w_down"])
-        h = shard(h, "moe_group", "experts", None, None)
+                g = jnp.einsum("ecd,edf->ecf", xe, p["w_gate"])
+                u = jnp.einsum("ecd,edf->ecf", xe, p["w_up"])
+                h = jnp.einsum("ecf,efd->ecd", jax.nn.silu(g) * u, p["w_down"])
+        h = shard(h, "experts", None, None)
 
-        # 4. combine: slot -> token, weighted sum over K (per group). The gather
-        # stays group-local: constrain operand and result to the group sharding
-        # so SPMD does not distribute the gather over the model axis and
-        # all-reduce the (Tg*K, D) result back.
-        hb = h.reshape(G, Eh * C, D)
-        ybuf = jnp.concatenate([hb, jnp.zeros((G, 1, D), h.dtype)], axis=1)
-        ybuf = shard(ybuf, "moe_group", None, None)
-        y = jnp.take_along_axis(
-            ybuf, slot_by_flat[..., None].astype(jnp.int32), axis=1
-        )  # (G, Tg*K, D)
-        y = shard(y, "moe_group", None, None)
-        y = y.reshape(T, K, D)
-        kept = kept.sum()
+        # 4. combine: slot -> token, weighted sum over K. Operand and result
+        # of the gather are constrained replicated so SPMD does not
+        # distribute the gather over the model axis and all-reduce the
+        # (T*K, D) result back.
+        ybuf = jnp.concatenate([h.reshape(Eh * C, D), jnp.zeros((1, D), h.dtype)])
+        ybuf = shard(ybuf, None, None)
+        y = shard(ybuf[slot_by_flat], None, None).reshape(T, K, D)
     y = jnp.where(held[..., None], y * gate_w[..., None].astype(y.dtype), 0).sum(axis=1)
 
     dropped = n_held - kept

@@ -8,9 +8,8 @@ over time and training converges to the uncompressed fixed point.
 
 Mechanics under pjit: the train step reduces gradients over the fast in-pod
 axes in full precision (XLA's native psum), then does the *pod* reduction on
-the int8 payload inside ``shard_map`` — 4x fewer bytes on the slowest links
-(which the roofline shows are the binding constraint for multi-pod data
-parallelism).
+the int8 payload inside ``shard_map`` — 4x fewer bytes on the slowest links,
+the ones between pods.
 """
 
 from __future__ import annotations

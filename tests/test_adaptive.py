@@ -384,3 +384,41 @@ class TestComputeEnergyAccount:
         assert led["total_energy_j"] == pytest.approx(
             led["transfer_energy_j"] + led["compute_energy_j"]
         )
+
+
+# ---------------------------------------------------------------------------
+# a diurnal day on the device fleet: the joules each policy pays
+# ---------------------------------------------------------------------------
+
+
+DIURNAL = [1, 2, 4, 8, 8, 4, 2, 1] * 2  # wave widths, two periods
+
+
+@pytest.mark.parametrize(
+    "placement,make_executor,joules,by_zone,resizes",
+    [
+        ("energy",
+         lambda: AdaptiveExecutor(inner=ConcurrentExecutor(max_workers=1),
+                                  min_workers=1, max_workers=8),
+         1.33177972, {"cloud": 16, "device": 16, "edge": 60}, 3),
+        ("pin", lambda: ConcurrentExecutor(max_workers=1),
+         2.91506648, {"cloud": 76, "device": 16}, None),
+        ("data_gravity", lambda: ConcurrentExecutor(max_workers=8),
+         2.39140848, {"cloud": 16, "device": 76}, None),
+    ],
+    ids=["adaptive_energy", "pin_pool1", "gravity_pool8"],
+)
+def test_diurnal_day_joules(placement, make_executor, joules, by_zone, resizes):
+    """Two diurnal periods of 256 KiB readings, wave widths 1..8..1: pin
+    floats the 60 analyzer firings to the cloud over the metered uplink,
+    data gravity drags them onto the battery-powered device, energy-aware
+    placement lands them on the edge rack and pays the fewest joules; the
+    adaptive pool resizes three times, from wave widths alone."""
+    ex = make_executor()
+    ws = _drive(_fan_ws(sorted(set(DIURNAL)), executor=ex, placement=placement),
+                DIURNAL, n=65536, seed=7)
+    ex.shutdown()
+    assert ws.stats()["topology"]["ledger"]["total_energy_j"] == pytest.approx(joules, rel=1e-9)
+    assert ws.stats()["topology"]["placement"]["by_zone"] == by_zone
+    if resizes is not None:
+        assert len(ex.scale_history) == resizes

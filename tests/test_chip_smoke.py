@@ -83,14 +83,3 @@ def test_compile_cache_dir(from_env, monkeypatch, tmp_path):
             assert jax.config.jax_compilation_cache_dir == path
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
-
-
-def test_bench_runner_parent_initialises_no_backend():
-    code = (
-        "import benchmarks.run as r; r._all_benches(); "
-        "from jax._src import xla_bridge; "
-        "print(xla_bridge.backends_are_initialized())"
-    )
-    r = _run(["-c", code], REPO, JAX_PLATFORMS="cpu", PYTHONPATH=os.path.join(REPO, "src"))
-    assert r.returncode == 0, r.stderr[-2000:]
-    assert r.stdout.strip().splitlines()[-1] == "False"

@@ -441,6 +441,35 @@ class TestCompaction:
             ws.registry, docs=False
         )
 
+    def test_archived_rounds_replay_as_the_full_history(self, tmp_path):
+        """Eight rounds of 40 fresh pushes, rotating every 256 records,
+        each round evicting all but four AVs and compacting into an archive:
+        2,907 records fold away, the restart reads a checkpoint and one
+        record, and that replay is byte-identical to the 2,908-record
+        oracle over the archive; the last four rounds keep at most twice
+        round one's bytes on disk."""
+        base = str(tmp_path / "ws.jsonl")
+        archive = str(tmp_path / "archive")
+        ws, norm, _ = _chain_ws(base, journal_rotate_records=256)
+        sizes = []
+        for r in range(8):
+            for i in range(40):
+                ws.push(norm, x=np.full(64, float(r * 40 + i), np.float32))
+            for uid in ws.registry.all_avs()[:-4]:
+                av = ws.registry.get_av(uid)
+                if not av.uri.startswith("ghost://"):
+                    ws.store.evict_local(av.uri)
+            ws.compact_journal(retire_evicted=True, archive_dir=archive)
+            sizes.append(ws.journal.stats()["bytes_on_disk"])
+        ws.journal.flush()
+        oracle = replay_files(_oracle_files(base, archive))
+        restored = replay_journal(base)
+        assert ws.journal.stats()["records_compacted"] == 2907
+        assert (oracle.records, restored.records) == (2908, 2)
+        assert _fp(restored.registry) == _fp(oracle.registry)
+        assert _fp(restored.registry, docs=False) == _fp(ws.registry, docs=False)
+        assert max(sizes[4:]) <= 2 * sizes[0], f"journal grew unbounded: {sizes}"
+
     def test_zone_segment_journal_refuses_compact(self, tmp_path):
         seg = Journal(tmp_path / "m.jsonl.seg-a", segment="a", flush_every_n=1)
         seg.append("anomaly", {"task": "t", "note": "x"}, seq=5)

@@ -1,5 +1,5 @@
-"""Distribution layer: sharding rules (divisibility fallbacks), HLO cost
-walker, elastic resharding, train-step numerics on the host mesh."""
+"""Distribution layer: sharding rules (divisibility fallbacks), elastic
+resharding, train-step numerics on the host mesh."""
 
 import jax
 import jax.numpy as jnp
@@ -13,7 +13,6 @@ from repro.dist.sharding import (
     make_rules,
     pspec_for_axes,
 )
-from repro.roofline.hlo_costs import hlo_costs, parse_module
 
 
 class FakeMesh:
@@ -87,58 +86,6 @@ def test_cache_axes_match_cache_structure():
             is_leaf=lambda x: isinstance(x, tuple)
             and all(isinstance(e, (str, type(None))) for e in x),
         )
-
-
-# ---------------------------------------------------------------------------
-# HLO cost walker on synthetic HLO
-# ---------------------------------------------------------------------------
-
-SYNTH_HLO = """
-HloModule test
-
-%body (p: (s32[], f32[8,8])) -> (s32[], f32[8,8]) {
-  %p = (s32[], f32[8,8]{1,0}) parameter(0)
-  %i = s32[] get-tuple-element(%p), index=0
-  %x = f32[8,8]{1,0} get-tuple-element(%p), index=1
-  %d = f32[8,8]{1,0} dot(%x, %x), lhs_contracting_dims={1}, rhs_contracting_dims={0}
-  %ar = f32[8,8]{1,0} all-reduce(%d), replica_groups=[16,16]<=[256], to_apply=%add
-  %one = s32[] constant(1)
-  %i2 = s32[] add(%i, %one)
-  ROOT %t = (s32[], f32[8,8]{1,0}) tuple(%i2, %ar)
-}
-
-%cond (p: (s32[], f32[8,8])) -> pred[] {
-  %p = (s32[], f32[8,8]{1,0}) parameter(0)
-  %i = s32[] get-tuple-element(%p), index=0
-  %n = s32[] constant(10)
-  ROOT %lt = pred[] compare(%i, %n), direction=LT
-}
-
-ENTRY %main (a: f32[8,8]) -> (s32[], f32[8,8]) {
-  %a = f32[8,8]{1,0} parameter(0)
-  %z = s32[] constant(0)
-  %t0 = (s32[], f32[8,8]{1,0}) tuple(%z, %a)
-  ROOT %w = (s32[], f32[8,8]{1,0}) while(%t0), condition=%cond, body=%body, backend_config={"known_trip_count":{"n":"10"}}
-}
-"""
-
-
-def test_walker_counts_trip_multiplied_dots_and_collectives():
-    c = hlo_costs(SYNTH_HLO, 256)
-    # dot: 2*8*8*8 flops per trip, 10 trips (+ trivial adds)
-    assert c["flops"] == pytest.approx(2 * 8 * 8 * 8 * 10, rel=0.05)
-    ar = c["collectives"]["all-reduce"]
-    assert ar["count"] == 10
-    assert ar["bytes"] == 8 * 8 * 4 * 10
-    # ring factor 2*(16-1)/16 with group size 16 from iota groups
-    assert ar["weighted"] == pytest.approx(8 * 8 * 4 * 10 * 2 * 15 / 16)
-    assert c["unknown_trip_whiles"] == 0
-
-
-def test_walker_parse_module_shapes():
-    comps, entry = parse_module(SYNTH_HLO)
-    assert entry == "main"
-    assert {"body", "cond", "main"} <= set(comps)
 
 
 # ---------------------------------------------------------------------------

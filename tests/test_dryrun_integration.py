@@ -1,6 +1,6 @@
 """Integration: the multi-pod dry-run machinery end to end, in a subprocess
 (it needs the 512-fake-device XLA flag, which must not leak into this
-process). One cheap cell per mesh proves lower+compile+roofline+record."""
+process). One cheap cell per mesh proves lower+compile+record."""
 
 import json
 import os
@@ -47,10 +47,9 @@ def test_dryrun_cell_compiles_and_records(multipod, tmp_path):
     )
     rec = json.load(open(rec_path))
     assert rec["n_devices"] == (512 if multipod else 256)
-    assert rec["t_memory"] > 0 and rec["bottleneck"] in ("compute", "memory", "collective")
     assert rec["memory_analysis"] is not None
     assert rec["state_gb_per_device"] < 16.0
-    assert rec["collectives"]["total_weighted"] >= 0
+    assert rec["compile_seconds"] > 0
 
 
 def test_dryrun_skip_row_recorded():

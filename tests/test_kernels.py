@@ -142,19 +142,3 @@ def test_blocked_attention_matches_reference():
         out = blocked_attention(q, k, v, causal=True, window=window, block_q=64, block_kv=32)
         ref = reference_attention(q, k, v, causal=True, window=window)
         np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
-
-
-def test_causal_skip_equivalence():
-    """The growing-window unrolled attention (hillclimb lever) is exact."""
-    from repro.models.attention import blocked_attention
-
-    B, L, H, KVH, Dh = 1, 256, 4, 2, 32
-    q = jnp.asarray(RNG.randn(B, L, H, Dh), jnp.float32)
-    k = jnp.asarray(RNG.randn(B, L, KVH, Dh), jnp.float32)
-    v = jnp.asarray(RNG.randn(B, L, KVH, Dh), jnp.float32)
-    for window in (0, 96):
-        base = blocked_attention(q, k, v, causal=True, window=window, block_q=64, block_kv=64)
-        skip = blocked_attention(
-            q, k, v, causal=True, window=window, block_q=64, block_kv=64, causal_skip=True
-        )
-        np.testing.assert_allclose(base, skip, rtol=2e-5, atol=2e-5)

@@ -1,51 +1,14 @@
-"""Regression tests for the hillclimb levers: group-local MoE dispatch,
-causal block-skipping, head padding — each must be numerically equivalent
-(or exactly characterized) vs the faithful baseline."""
-
-import dataclasses
+"""MoE capacity bins and the bf16 gradient cast: ``expert_capacity`` may only
+shrink as a dispatch call covers fewer tokens, and ``grad_cast`` is the
+identity forward and casts the cotangent backward."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from repro.configs import get_config
-from repro.models.common import ParamBuilder, grad_cast
-from repro.models.moe import expert_capacity, init_moe, moe_ffn
-from repro.models.registry import build_model, train_loss
-
-
-# multi-minute model/kernel path: runs in the full CI job only
-pytestmark = pytest.mark.slow
-
-
-
-def _moe_params(cfg, dtype=jnp.float32):
-    pb = ParamBuilder(jax.random.key(0), dtype)
-    return jax.tree.map(
-        lambda x: x[0],
-        init_moe(pb, cfg),
-        is_leaf=lambda x: isinstance(x, tuple) and hasattr(x[0], "dtype"),
-    )
-
-
-def test_moe_groups_exact_with_generous_capacity():
-    cfg = dataclasses.replace(get_config("mixtral-8x7b").reduced(), capacity_factor=8.0)
-    p = _moe_params(cfg)
-    x = jax.random.normal(jax.random.key(1), (4, 16, cfg.d_model))
-    y1, a1 = moe_ffn(p, cfg, x)
-    for g in (2, 4):
-        y2, a2 = moe_ffn(p, dataclasses.replace(cfg, moe_groups=g), x)
-        np.testing.assert_array_equal(np.asarray(y1), np.asarray(y2))
-        assert float(a2["dropped_frac"]) == 0.0
-
-
-def test_moe_groups_nondivisible_falls_back():
-    cfg = dataclasses.replace(get_config("mixtral-8x7b").reduced(), moe_groups=7)
-    p = _moe_params(cfg)
-    x = jax.random.normal(jax.random.key(1), (4, 16, cfg.d_model))  # 64 % 7 != 0
-    y, _ = moe_ffn(p, cfg, x)  # must not crash (G falls back to 1)
-    assert y.shape == x.shape
+from repro.models.common import grad_cast
+from repro.models.moe import expert_capacity
 
 
 def test_moe_group_capacity_scales():
@@ -53,55 +16,6 @@ def test_moe_group_capacity_scales():
     c_global = expert_capacity(1024, cfg)
     c_group = expert_capacity(1024 // 4, cfg)
     assert c_group <= c_global
-
-
-def test_causal_skip_train_loss_identical():
-    cfg = get_config("internlm2-20b").reduced()
-    m0 = build_model(cfg)
-    m1 = build_model(dataclasses.replace(cfg, causal_skip=True, block_q=16, block_kv=16))
-    params, _ = m0.init(jax.random.key(0))
-    B, L = 2, 64
-    toks = jax.random.randint(jax.random.key(1), (B, L), 0, cfg.vocab)
-    batch = {"tokens": toks, "labels": toks}
-    l0, _ = train_loss(m0, params, batch)
-    l1, _ = train_loss(m1, params, batch)
-    assert float(l0) == pytest.approx(float(l1), rel=1e-5)
-
-
-def test_pad_heads_zero_contribution_at_init():
-    """With identical real-head weights, padded heads must not change the
-    output (their wo rows are zero)."""
-    cfg = get_config("minicpm3-4b").reduced()
-    cfgp = dataclasses.replace(cfg, pad_heads=2)
-    m0, mp = build_model(cfg), build_model(cfgp)
-    p0, _ = m0.init(jax.random.key(0))
-    pp, _ = mp.init(jax.random.key(0))
-
-    # splice the unpadded weights into the padded tree (pad rows keep init)
-    def splice(path_p, pad_leaf, real_leaf):
-        if pad_leaf.shape == real_leaf.shape:
-            return real_leaf
-        # head-padded dim: copy real heads, zero the rest where wo-like
-        idx = [i for i, (a, b) in enumerate(zip(pad_leaf.shape, real_leaf.shape)) if a != b]
-        assert len(idx) == 1
-        ax = idx[0]
-        pad = pad_leaf
-        sl = [slice(None)] * pad.ndim
-        sl[ax] = slice(0, real_leaf.shape[ax])
-        pad = pad.at[tuple(sl)].set(real_leaf)
-        slp = [slice(None)] * pad.ndim
-        slp[ax] = slice(real_leaf.shape[ax], None)
-        return pad.at[tuple(slp)].set(0.0)
-
-    pp2 = jax.tree.map(lambda a, b: splice(None, a, b), pp, p0)
-    B, L = 2, 16
-    toks = jax.random.randint(jax.random.key(1), (B, L), 0, cfg.vocab)
-    x0 = m0.embed(p0, toks)
-    xp = mp.embed(pp2, toks)
-    pos = jnp.broadcast_to(jnp.arange(L)[None], (B, L))
-    o0, _, _ = m0.trunk(p0, x0, pos)
-    op, _, _ = mp.trunk(pp2, xp, pos)
-    np.testing.assert_allclose(np.asarray(o0), np.asarray(op), rtol=1e-4, atol=1e-5)
 
 
 def test_grad_cast_casts_cotangent():

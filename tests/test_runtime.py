@@ -210,6 +210,29 @@ class TestKnobValidation:
 
 @needs_fork
 class TestProcessPool:
+    def test_pipe_carries_exact_control_bytes_only(self):
+        """An 8-wide fan pushed four times: 32 tasks run in workers, the
+        pipes carry 5,952 control bytes out and 5,920 back and no payload
+        byte, and the output and visitor events are the thread pool's."""
+        runs = {}
+        for label, ex in (
+            ("threads", ConcurrentExecutor(max_workers=8)),
+            ("processes", ProcessExecutor(max_workers=8)),
+        ):
+            ws = _drive_fan(_fan_ws(ex, width=8, journal_path=False), rounds=4, n=256)
+            runs[label] = (
+                ws.value_of(ws.pipeline.tasks["reduce"].last_outputs["total"]),
+                sorted((t, e["event"]) for t in ws.tasks() for e in ws.visitor_log(t)),
+                ex.stats(),
+            )
+            ex.shutdown()
+        st = runs["processes"][2]
+        assert st["tasks_remote"] == 32
+        assert st["control_bytes_sent"] == 5952
+        assert st["control_bytes_received"] == 5920
+        assert st["payload_bytes_over_pipe"] == 0
+        assert runs["processes"][:2] == runs["threads"][:2]
+
     def test_matches_inline_and_moves_no_payload_bytes(self):
         base = _drive_fan(_fan_ws(InlineExecutor()))
         ex = ProcessExecutor(max_workers=4)

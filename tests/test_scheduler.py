@@ -129,6 +129,52 @@ def test_enqueued_scales_with_events_not_circuit_size():
     assert sched["scan_reduction_x"] > 10
 
 
+@pytest.mark.parametrize(
+    "hot,cold,pushes,scan_reduction_x",
+    [(1, 0, 5, 2.0), (2, 14, 10, 24.0), (3, 13, 200, 64 / 3)],
+)
+def test_scan_reduction_is_exact(hot, cold, pushes, scan_reduction_x):
+    """A push down a hot chain of ``hot`` tasks enqueues exactly those
+    tasks, while a polling engine scans the whole circuit once per wave and
+    once more to find quiescence: hot + cold tasks x (hot + 1) rounds."""
+    ws = _chain_ws(n=hot)
+    for i in range(cold):
+        ws.task(lambda q: {"r": q}, name=f"cold{i}", inputs=["q"], outputs=["r"])
+    for i in range(pushes):
+        ws.push("t0", x=i)
+    sched = ws.stats()["scheduler"]
+    assert sched["tasks_enqueued"] == hot * pushes
+    assert sched["polling_scan_equivalent"] == (hot + cold) * (hot + 1) * pushes
+    assert sched["scan_reduction_x"] == pytest.approx(scan_reduction_x, rel=1e-12)
+    assert ws.value_of(ws.pipeline.tasks[f"t{hot - 1}"].last_outputs["y"]) == pushes - 1 + hot
+
+
+def test_coalescing_cuts_waves_not_results():
+    """400 arrivals through a two-stage chain: one wave per firing (800)
+    uncoalesced, 26 with both stages coalescing up to 32 snapshots, and the
+    same outputs and visitor events either way."""
+    runs = {}
+    for max_batch in (None, 32):
+        ws = Workspace("coalesce", executor=InlineExecutor(), topology=False, cache=False)
+        inc = ws.task(lambda x: {"y": x + 1.0}, name="inc", inputs=["x"], outputs=["y"])
+        dbl = ws.task(lambda y: {"z": y * 2.0}, name="dbl", inputs=["y"], outputs=["z"])
+        inc["y"] >> dbl["y"]
+        if max_batch:
+            inc.coalesce(max_batch)
+            dbl.coalesce(max_batch)
+        for i in range(400):
+            ws.inject(inc, "x", np.full(8, float(i), np.float32))
+        ws.manager.propagate()
+        log = [e for t in ws.tasks() for e in ws.visitor_log(t)]
+        runs[max_batch] = (
+            ws.stats()["scheduler"]["waves"],
+            [(e["task"], e["event"]) for e in log],
+            [ws.registry.get_av(e["av_uid"]).chash for e in log if e["event"] == "emitted"],
+        )
+    assert runs[None][0] == 800 and runs[32][0] == 26
+    assert runs[None][1:] == runs[32][1:]
+
+
 def test_cycle_bounded_by_per_task_fire_budget():
     pipe = Pipeline("cyc")
     pipe._add_task(SmartTask("a", lambda x: {"y": x + 1}, ["x"], ["y"]))

@@ -177,6 +177,31 @@ class TestIsolationProperty:
         # lineage reads stay tenant-scoped: B's registry holds only B's AVs
         assert not set(a.ws.registry.all_avs()) & set(b.ws.registry.all_avs())
 
+    def test_fleet_dedup_ratio_is_the_tenant_count(self, tmp_path):
+        """64 journaled tenants each push the same 8 artifacts, rotated:
+        of the 2,048 logical firings only the first tenant's 32 execute (a
+        64x dedup), and each of the other 63 replays its 32 from the shared
+        store, saving the same 571 bytes."""
+        hub = WorkspaceHub(
+            "fleet", journal_path=str(tmp_path / "hub.jsonl"),
+            executor_factory=InlineExecutor,
+            workspace_defaults={"topology": False},
+        )
+        sessions = [hub.create(f"tenant-{i:03d}", owner="ops") for i in range(64)]
+        for i, s in enumerate(sessions):
+            _wire(s)
+            for k in range(8):
+                s.push("src", x=_payload((i + k) % 8))
+        memo = hub.memo.stats()
+        hub.shutdown()
+        logical = 64 * 8 * 4
+        assert memo["offers"] == logical and memo["entries"] == 32
+        assert memo["executions_avoided"] == memo["dedup_hits"] == 2016
+        assert logical / (logical - memo["executions_avoided"]) == 64.0
+        assert memo["bytes_saved"] == 63 * 571
+        assert len(memo["by_tenant"]) == 63
+        assert all(v == {"hits": 32, "bytes_saved": 571} for v in memo["by_tenant"].values())
+
     def test_dedup_falls_through_on_evicted_origin(self):
         # an unresolvable origin output must fall back to a real run, not
         # crash and not leak a bogus credit

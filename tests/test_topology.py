@@ -255,6 +255,36 @@ class TestDataGravityPlacement:
         assert grav["bytes_moved_crosszone"] * 2 == pin["bytes_moved_crosszone"]
         assert grav["transfer_energy_j"] < pin["transfer_energy_j"]
 
+    @pytest.mark.parametrize(
+        "sensors,rounds,n,pin_bytes,gravity_bytes",
+        [(4, 2, 128, 12_288, 3_072), (8, 3, 256, 73_728, 9_216)],
+    )
+    def test_fan_in_sets_the_byte_reduction(self, sensors, rounds, n, pin_bytes, gravity_bytes):
+        """All-to-cloud moves every raw reading across a zone boundary,
+        gravity only each zone's aggregate: the cut is the fan-in, 8x at 8
+        sensors a zone (73,728 -> 9,216 B, 0.05 J/MB). Placement and zoned
+        execution change neither the merge order nor the visitor events, and
+        the zoned run charges the ledger exactly as the inline one."""
+        runs = {
+            label: _fingerprint(_drive(
+                _iot_ws(placement, executor=ex, sensors=sensors),
+                rounds=rounds, n=n, sensors=sensors,
+            ))
+            for label, placement, ex in (
+                ("pin", "pin", None),
+                ("gravity", "data_gravity", None),
+                ("gravity_zoned", "data_gravity",
+                 ZonedExecutor(inner=ConcurrentExecutor(max_workers=4))),
+            )
+        }
+        grav = runs["gravity"]["ledger"]
+        assert runs["pin"]["ledger"]["bytes_moved_crosszone"] == pin_bytes
+        assert grav["bytes_moved_crosszone"] == gravity_bytes
+        assert grav["transfer_energy_j"] == pytest.approx(gravity_bytes / 1e6 * 0.05)
+        for key in ("merge_order", "events"):
+            assert runs["pin"][key] == runs["gravity"][key] == runs["gravity_zoned"][key]
+        assert runs["gravity_zoned"]["ledger"] == grav
+
     def test_pinned_tasks_resist_gravity(self):
         topo = Topology.three_zone()
         ws = Workspace("pins", topology=topo, placement="data_gravity", cache=False)
